@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import argparse
 
-from benchmarks.common import QUICK, emit
+from benchmarks.common import QUICK, emit, start
 from repro.configs.iemas_cluster import RouterConfig
 from repro.core.adversary import POLICIES, AdversaryMix
 from repro.core.valuation import client_value
@@ -134,4 +134,5 @@ def main():
 
 
 if __name__ == "__main__":
+    start()
     main()

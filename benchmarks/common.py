@@ -7,6 +7,14 @@ import time
 QUICK = os.environ.get("BENCH_QUICK", "0") == "1"
 
 
+def start() -> None:
+    """Start-up every benchmark entry point runs first (never on import):
+    the persistent compile cache (`repro.utils.compile_cache`)."""
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
+
 def timed(fn, *args, **kw):
     t0 = time.perf_counter()
     out = fn(*args, **kw)

@@ -47,7 +47,7 @@ import time
 
 import numpy as np
 
-from benchmarks.common import QUICK, emit
+from benchmarks.common import QUICK, emit, start
 from repro.core.baselines import GraphSchedulerRouter
 from repro.core.valuation import ValuationConfig, client_value
 from repro.serving import (EventSimulator, PoissonArrivals, SimCluster,
@@ -149,4 +149,5 @@ def main():
 
 
 if __name__ == "__main__":
+    start()
     main()

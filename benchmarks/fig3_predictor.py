@@ -5,7 +5,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.common import QUICK, emit
+from benchmarks.common import QUICK, emit, start
 from repro.core import IEMASRouter
 from repro.core.pricing import observed_cost
 from repro.serving import SimCluster, WorkloadSpec, generate, run_workload
@@ -49,4 +49,5 @@ def run():
 
 
 if __name__ == "__main__":
+    start()
     run()

@@ -7,7 +7,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.common import QUICK, emit
+from benchmarks.common import QUICK, emit, start
 from repro.core import IEMASRouter, ValuationConfig, client_value
 from repro.core.baselines import BASELINES
 from repro.serving import SimCluster, WorkloadSpec, generate, run_workload
@@ -39,4 +39,5 @@ def run():
 
 
 if __name__ == "__main__":
+    start()
     run()

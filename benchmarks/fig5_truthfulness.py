@@ -6,7 +6,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.common import QUICK, emit, synthetic_market
+from benchmarks.common import QUICK, emit, start, synthetic_market
 from repro.core.auction import client_utilities, run_auction
 from repro.core.solvers import available_solvers
 
@@ -57,4 +57,5 @@ def run(rounds: int | None = None, n: int = 12, m: int = 5,
 
 
 if __name__ == "__main__":
+    start()
     run()

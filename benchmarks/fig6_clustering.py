@@ -7,7 +7,7 @@ import time
 
 import numpy as np
 
-from benchmarks.common import QUICK, emit, synthetic_market
+from benchmarks.common import QUICK, emit, start, synthetic_market
 from repro.core.auction import run_auction
 from repro.core.hub import cluster_agents
 
@@ -55,4 +55,5 @@ def run(n: int | None = None, m: int | None = None):
 
 
 if __name__ == "__main__":
+    start()
     run()

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.common import QUICK, emit, synthetic_market
+from benchmarks.common import QUICK, emit, start, synthetic_market
 from repro.core.auction import client_utilities, run_auction
 
 
@@ -59,4 +59,5 @@ def run(n: int | None = None, m: int | None = None):
 
 
 if __name__ == "__main__":
+    start()
     run()

@@ -40,7 +40,7 @@ import time
 
 import numpy as np
 
-from benchmarks.common import QUICK, emit
+from benchmarks.common import QUICK, emit, start
 from repro.configs.iemas_cluster import SCALE_128
 from repro.serving import (EventSimulator, PoissonArrivals, RoutingProfiler,
                            SimCluster, WorkloadSpec, iter_dialogues,
@@ -249,4 +249,5 @@ def main():
 
 
 if __name__ == "__main__":
+    start()
     main()

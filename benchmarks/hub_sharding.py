@@ -44,7 +44,7 @@ import time
 
 import numpy as np
 
-from benchmarks.common import QUICK, emit, synthetic_market
+from benchmarks.common import QUICK, emit, start, synthetic_market
 from repro.core.auction import SPILL_HUB, run_auction, run_sharded_auction
 from repro.core.hub import cluster_agents
 
@@ -252,4 +252,5 @@ def main():
 
 
 if __name__ == "__main__":
+    start()
     main()

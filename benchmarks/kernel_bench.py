@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.common import emit, timed
+from benchmarks.common import emit, start, timed
 from repro.core.affinity import PrefixLedger
 from repro.utils.timing import bench_call
 
@@ -55,7 +55,7 @@ def run():
          f"max_err={err:.1e}")
 
     # auction bidding round: interpret-mode kernel vs jnp oracle (bit-equal)
-    from repro.kernels.ops import auction_bid_op
+    from repro.kernels.auction_bid import auction_bid
     from repro.kernels.ref import auction_bid_ref
 
     B = jnp.asarray(np.maximum(rng.uniform(-1, 4, (256, 384)), 0.0),
@@ -69,9 +69,9 @@ def run():
     active = jnp.asarray(rng.random(256) > 0.25)
     t_ref = bench_call(lambda: auction_bid_ref(B, ask, ask2, active, 0.01),
                        warmup=1, iters=3)
-    t_pal = bench_call(lambda: auction_bid_op(B, ask, ask2, active, 0.01),
+    t_pal = bench_call(lambda: auction_bid(B, ask, ask2, active, 0.01),
                        warmup=1, iters=3)
-    got = auction_bid_op(B, ask, ask2, active, 0.01)
+    got = auction_bid(B, ask, ask2, active, 0.01)
     want = auction_bid_ref(B, ask, ask2, active, 0.01)
     exact = all(bool(jnp.array_equal(g, w)) for g, w in zip(got, want))
     emit("kernels/auction_bid_256x384", t_pal,
@@ -95,4 +95,5 @@ def run():
 
 
 if __name__ == "__main__":
+    start()
     run()

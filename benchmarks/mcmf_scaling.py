@@ -38,7 +38,7 @@ from __future__ import annotations
 import argparse
 import time
 
-from benchmarks.common import QUICK, emit, synthetic_market
+from benchmarks.common import QUICK, emit, start, synthetic_market
 from repro.core.auction import run_auction
 
 
@@ -219,4 +219,5 @@ def main():
 
 
 if __name__ == "__main__":
+    start()
     main()

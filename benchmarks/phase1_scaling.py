@@ -28,7 +28,7 @@ import time
 
 import numpy as np
 
-from benchmarks.common import QUICK, emit
+from benchmarks.common import QUICK, emit, start
 from repro.core.predictor import (N_FEATURES, PredictorInput, PredictorPool,
                                   feature_tensor)
 from repro.core.pricing import TokenPrices
@@ -144,4 +144,7 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="gate size only, no jax; CI-friendly")
-    run(ap.parse_args().smoke)
+    smoke = ap.parse_args().smoke
+    if not smoke:
+        start()
+    run(smoke)

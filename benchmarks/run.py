@@ -40,7 +40,7 @@ from __future__ import annotations
 import sys
 import time
 
-from benchmarks.common import QUICK
+from benchmarks.common import QUICK, start
 
 
 def main() -> None:
@@ -97,4 +97,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    start()
     main()

@@ -34,7 +34,7 @@ from __future__ import annotations
 import argparse
 import time
 
-from benchmarks.common import QUICK, emit
+from benchmarks.common import QUICK, emit, start
 from repro.configs.iemas_cluster import SCALE_1K, SCALE_128
 from repro.serving import (EventSimulator, PoissonArrivals, RoutingProfiler,
                            SimCluster, WorkloadSpec, build_federation,
@@ -366,4 +366,5 @@ def main():
 
 
 if __name__ == "__main__":
+    start()
     main()

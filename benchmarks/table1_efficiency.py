@@ -7,7 +7,7 @@ IEMAS highest KV %, lowest cost, and lowest/most-competitive latency.
 """
 from __future__ import annotations
 
-from benchmarks.common import QUICK, emit, timed
+from benchmarks.common import QUICK, emit, start, timed
 from repro.core import IEMASRouter
 from repro.core.baselines import BASELINES
 from repro.serving import SimCluster, WorkloadSpec, generate, run_workload
@@ -40,4 +40,5 @@ def run(full: bool = False):
 
 
 if __name__ == "__main__":
+    start()
     run(full=True)

@@ -361,7 +361,7 @@ class PrefixLedger:
                                 extension_only_mask):
         """Batched LCP via the Pallas kernel, gathering padded ledger rows
         straight from the persistent arena (no per-pair Python rebuild)."""
-        from repro.kernels.ops import lcp_affinity_op
+        from repro.kernels.lcp_affinity import lcp_affinity
 
         n, m = len(prompts), len(agent_ids)
         max_p = max((len(p) for p in prompts), default=1)
@@ -375,7 +375,7 @@ class PrefixLedger:
             plen[j] = len(p)
         lmat = np.full((n, m, length), PAD_LEDGER, np.int32)
         lmat[:, :, : self.store.width] = self.store.tokens[rows]
-        lcp = np.asarray(lcp_affinity_op(pmat, lmat))  # [N, M]
+        lcp = np.asarray(lcp_affinity(pmat, lmat))  # [N, M]
         lcp = np.minimum(lcp, np.minimum(plen[:, None], llen))
         o = lcp / np.maximum(plen[:, None], 1)
         if extension_only_mask is not None:

@@ -5,8 +5,8 @@ difference is the forward bidding round, which runs as the
 `repro.kernels.auction_bid` Pallas kernel (per-request top-2 agent profits
 against the per-agent ask/ask2 quotes + segment-max scatter of bids into
 agent columns, tiled over the (n × m) weight matrix) instead of the
-pure-jnp transcription.  Off-TPU the kernel runs in interpret mode (the
-`repro.kernels.ops` dispatch), so the backend works — and is tested
+pure-jnp transcription.  Off-TPU the kernel runs in interpret mode
+(`repro.kernels.resolve_interpret`), so the backend works — and is tested
 bit-for-bit against the jnp oracle — everywhere, while on TPU the bidding
 round compiles to a real VMEM-tiled kernel.
 
@@ -61,11 +61,13 @@ def _bid_round_pallas(W, ask, ask2, active, eps):
     amortize per-program overhead in interpret mode; 128-row tiles keep
     real TPU weight tiles comfortably inside VMEM.
     """
-    from repro.kernels.ops import _interpret, auction_bid_op
+    from repro.kernels import resolve_interpret
+    from repro.kernels.auction_bid import auction_bid
 
     n = W.shape[0]
-    bn = _tile_split(n)[1] if _interpret() else min(n, _TILE_ROWS_TPU)
-    return auction_bid_op(W, ask, ask2, active, eps, bn=bn)
+    bn = (_tile_split(n)[1] if resolve_interpret(None)
+          else min(n, _TILE_ROWS_TPU))
+    return auction_bid(W, ask, ask2, active, eps, bn=bn)
 
 
 def _pad_plan(n: int, m: int, cmax: int, interpret: bool
@@ -98,9 +100,9 @@ def solve_dense_auction_pallas(w, caps, *, max_rounds: int = 200_000,
     counts = column_counts([int(c) for c in caps], n)
     K = int(counts.sum())
     if n and K:
-        from repro.kernels.ops import _interpret
+        from repro.kernels import resolve_interpret
 
-        pad = _pad_plan(n, m, int(counts.max()), _interpret())
+        pad = _pad_plan(n, m, int(counts.max()), resolve_interpret(None))
     else:
         pad = None
     return solve_dense_auction_jax(

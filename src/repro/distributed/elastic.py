@@ -63,11 +63,8 @@ def remesh(n_devices: int, *, data_model_ratio: float = 1.0,
         if score > best_score:
             best, best_score = (d, m), score
     d, m = best
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:  # pre-0.5 jax: meshes are implicitly Auto
-        return jax.make_mesh((d, m), ("data", "model"), devices=devices)
     return jax.make_mesh((d, m), ("data", "model"), devices=devices,
-                         axis_types=(axis_type.Auto,) * 2)
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def reshard_state(state, param_axes, mesh, rules_acts: dict,

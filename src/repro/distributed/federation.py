@@ -19,13 +19,17 @@ deterministic under ANY shard-advance interleave, which is what lets the
 process pool below overlap shard execution freely between epochs
 (tests/test_federation.py shuffles the advance schedule to prove it).
 
-Placement note: `launch/mesh.py` pins device meshes for the JAX training/
-kernel stack; the federation's shard workers are CPU-bound numpy loops,
-so `worker_slots` just bounds process fan-out by visible cores rather
-than claiming mesh devices.
+Placement note: the federation's shard workers are CPU-bound numpy loops,
+so `worker_slots` just bounds process fan-out by visible cores.  A chip
+belongs to one process, and the parent that spawns the workers has already
+imported JAX, so every worker starts with ``JAX_PLATFORMS=cpu`` in its
+environment and can never claim the accelerator; `build_federation`
+refuses process mode for real engines and device solvers, which would
+otherwise run on the workers' CPU.
 """
 from __future__ import annotations
 
+import contextlib
 import multiprocessing as mp
 import os
 from dataclasses import dataclass, field
@@ -43,6 +47,22 @@ def shard_seed(base_seed: int, super_id: int) -> int:
     """
     ss = np.random.SeedSequence((int(base_seed), int(super_id)))
     return int(ss.generate_state(1, np.uint32)[0] % (2**31))
+
+
+@contextlib.contextmanager
+def _cpu_only_children():
+    """Hold ``JAX_PLATFORMS=cpu`` in the environment while a worker is
+    spawned: the spawned interpreter inherits it, so JAX in the worker never
+    opens the accelerator the parent may hold."""
+    prev = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        yield
+    finally:
+        if prev is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = prev
 
 
 def worker_slots(requested: int | None = None) -> int:
@@ -111,7 +131,8 @@ class ProcessShardHandle:
     split into `advance_async` + `wait` so the parent overlaps all
     shards' epoch work — the actual concurrency win.  Uses the spawn
     start method: the parent has jax initialized, and forking a process
-    with live jax threadpools is not safe.
+    with live jax threadpools is not safe.  The worker starts with
+    ``JAX_PLATFORMS=cpu`` (`_cpu_only_children`).
     """
 
     def __init__(self, spec: ShardSpec, *, ctx: str = "spawn"):
@@ -120,7 +141,8 @@ class ProcessShardHandle:
         self._conn, child = context.Pipe()
         self._proc = context.Process(target=_shard_worker,
                                      args=(child, spec), daemon=True)
-        self._proc.start()
+        with _cpu_only_children():
+            self._proc.start()
         child.close()
         self._pending = False
         status, payload = self._conn.recv()     # startup ack

@@ -1,9 +1,20 @@
 """Pallas kernels for the framework's compute hot-spots.
 
-Three-file pattern per op: ``<name>.py`` holds the `pl.pallas_call` kernel
-(compiled on TPU, interpret mode elsewhere), ``ref.py`` the simplest-possible
-pure-jnp oracle it is validated against, and ``ops.py`` the dispatch wrapper
-callers import.  Current kernels: LCP affinity (router Phase 1), the dense
+Two-file pattern per op: ``<name>.py`` holds the `pl.pallas_call` kernel,
+which callers import (compiled on TPU, interpret mode elsewhere: see
+`resolve_interpret`), and ``ref.py`` the simplest-possible pure-jnp oracle
+it is validated against.  Current kernels: LCP affinity (router Phase 1), the dense
 auction's forward-bidding round (router Phase 2), flash/decode attention,
 WKV6 and SSD recurrences (serving engines).
 """
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Every kernel's ``interpret`` convention: an explicit bool is kept;
+    None means compiled Pallas on a TPU backend and interpret mode on any
+    other backend."""
+    if interpret is None:
+        import jax
+
+        return jax.default_backend() != "tpu"
+    return interpret

@@ -52,6 +52,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 BN = 8          # request rows per tile
 LANE = 128      # agent-dimension padding multiple on real hardware
 
@@ -110,7 +112,7 @@ def _bid_kernel(w_ref, a1_ref, a2_ref, act_ref, e_ref,
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
 def auction_bid(W, ask, ask2, active, eps, *, bn: int = BN,
-                interpret: bool = True):
+                interpret: bool | None = None):
     """One Jacobi forward-bidding round over agent-level weights.
 
     ``W``: [n, m] non-negative weights; ``ask``/``ask2``: [m] cheapest and
@@ -126,6 +128,7 @@ def auction_bid(W, ask, ask2, active, eps, *, bn: int = BN,
     internally; callers that pre-pad to power-of-two shape buckets hit a
     single trace across batch-size wobble.
     """
+    interpret = resolve_interpret(interpret)
     W = jnp.asarray(W)
     n, m = W.shape
     pn = (-n) % bn

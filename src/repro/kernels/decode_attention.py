@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -41,7 +43,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, m_ref, l_ref, acc_ref,
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
 def decode_attention(q, k_cache, v_cache, valid, *, bk: int = 256,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """q: [B, H, d]; caches: [B, M, Hkv, d]; valid: [B, M] bool -> [B, H, d]."""
     b, h, d = q.shape
     m_len, hkv = k_cache.shape[1], k_cache.shape[2]
@@ -81,7 +83,7 @@ def decode_attention(q, k_cache, v_cache, valid, *, bk: int = 256,
             jax.ShapeDtypeStruct((b * hkv, nk, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b * hkv, nk, g, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qg, kk, vv, val)
 
     # pass 2: combine partials over the nk block axis (log-sum-exp)

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -63,7 +65,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 @functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
                                               "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    bq: int = 128, bk: int = 128, interpret: bool = True):
+                    bq: int = 128, bk: int = 128,
+                    interpret: bool | None = None):
     """q: [B, Sq, H, d]; k, v: [B, Sk, Hkv, d] -> [B, Sq, H, d]."""
     b, sq, h, d = q.shape
     sk, hkv = k.shape[1], k.shape[2]
@@ -106,7 +109,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qq, kk, vv)
     out = out.reshape(b, h, sq + pq, d).transpose(0, 2, 1, 3)
     return out[:, :sq]

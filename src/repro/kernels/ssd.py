@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 CHUNK = 16
 
 
@@ -68,7 +70,7 @@ def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, la_ref, dskip_ref, y_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd(x, bmat, cmat, dt, a_log, d_skip, *, chunk: int = CHUNK,
-        interpret: bool = True):
+        interpret: bool | None = None):
     """x: [B,S,H,hd]; bmat,cmat: [B,S,ds]; dt: [B,S,H] (post-softplus);
     a_log, d_skip: [H]. Zero initial state. Returns (y, sT [B,H,hd,ds])."""
     b, s, h, hd = x.shape
@@ -111,7 +113,7 @@ def ssd(x, bmat, cmat, dt, a_log, d_skip, *, chunk: int = CHUNK,
             jax.ShapeDtypeStruct((b * h, hd, ds), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, ds), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xx, bb, cc, dtt, laa, dsk)
     y = y.reshape(b, h, ss, hd).transpose(0, 2, 1, 3)
     return y[:, :s], sT.reshape(b, h, hd, ds)

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 CHUNK = 16
 
 
@@ -69,7 +71,8 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, sT_ref, s_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6(r, k, v, log_w, u, *, chunk: int = CHUNK, interpret: bool = True):
+def wkv6(r, k, v, log_w, u, *, chunk: int = CHUNK,
+         interpret: bool | None = None):
     """r,k,v,log_w: [B,S,H,dk] (dv == dk); u: [H,dk].
 
     Returns (o [B,S,H,dk], sT [B,H,dk,dk]); initial state is zero (callers
@@ -107,7 +110,7 @@ def wkv6(r, k, v, log_w, u, *, chunk: int = CHUNK, interpret: bool = True):
             jax.ShapeDtypeStruct((b * h, dk, dk), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dk), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rr, kk, vv, lw, uu)
     o = o.reshape(b, h, ss, dk).transpose(0, 2, 1, 3)
     return o[:, :s], sT.reshape(b, h, dk, dk)

@@ -40,6 +40,8 @@ from repro.serving import (DAG_WORKLOADS, EventSimulator, RoutingProfiler,
                            SimCluster, WorkloadSpec, build_federation,
                            generate, iter_dialogues, load_trace,
                            make_arrivals, run_workload)
+from repro.serving.federation import DEVICE_SOLVERS
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def build_router(name: str, infos, *, n_hubs: int = 1, payment_mode="warmstart",
@@ -198,6 +200,13 @@ def main():
             ap.error("--adversary seeds its population over one global "
                      "cluster; strategic-agent studies run single-heap "
                      "(benchmarks/adversarial.py)")
+        if args.federation_parallel == "process" and (
+                args.engine_mode == "real"
+                or args.solver in DEVICE_SOLVERS):
+            ap.error("--federation-parallel process runs each super-hub in "
+                     "a CPU-only worker; real engines and device solvers "
+                     f"({', '.join(DEVICE_SOLVERS)}) need the chip, so run "
+                     "them with --federation-parallel inline")
     if args.incremental:
         from repro.core.solvers import get_solver
         if args.sim_mode != "event":
@@ -209,6 +218,7 @@ def main():
                      "--warm-start with a warm-capable solver "
                      "(e.g. --solver dense)")
 
+    enable_compile_cache()
     engine_mode = args.engine_mode or (
         "analytic" if args.sim_mode == "event" else "real")
     spec = WorkloadSpec(args.workload, n_dialogues=args.dialogues,

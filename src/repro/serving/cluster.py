@@ -19,6 +19,11 @@ Engine modes: ``engine_mode="real"`` (default) runs the reduced JAX models
 swaps in `repro.serving.analytic.AnalyticEngine`, whose service times come
 from a roofline model calibrated against the real engines, enabling the
 128-agent / 10k-dialogue scale runs of `repro.serving.simulator`.
+``engine_config`` gives every real engine one model (e.g. a published
+config at full width) in place of the per-class reduced ones. Real engines
+are placed round-robin over ``jax.devices()`` (`agent_device`), so on a
+four-chip host each agent's parameters, caches and jitted calls sit on its
+own chip.
 
 `run_workload` below is the closed-loop, fixed-population oracle loop; the
 event-driven open-loop driver for scale runs lives in
@@ -33,8 +38,10 @@ import zlib
 from collections import Counter, deque
 from dataclasses import dataclass, field, replace
 
+import jax
 import numpy as np
 
+from repro.configs.base import ModelConfig
 from repro.configs.iemas_cluster import (DEFAULT_ROUTER, MODEL_CLASSES,
                                          AgentProfile, RouterConfig,
                                          agent_profiles)
@@ -46,6 +53,12 @@ from repro.serving.evaluator import SimulatedSkillEvaluator
 from repro.serving.telemetry import TelemetryTracker
 from repro.serving.workload import DialogueScript
 from repro.utils.timing import phase_scope
+
+
+def agent_device(index: int, devices: list):
+    """Round-robin placement: the ``index``-th real engine a cluster builds
+    runs on ``devices[index % len(devices)]`` (one device: all share it)."""
+    return devices[index % len(devices)]
 
 
 def _engine_config(model_class: str, vocab: int):
@@ -107,13 +120,22 @@ class SimCluster:
                  quarantine_cooldown: float = 30.0, warmup: bool = False,
                  engine_mode: str = "real",
                  adversary_mix: AdversaryMix | None = None,
-                 profiles: list[AgentProfile] | None = None):
+                 profiles: list[AgentProfile] | None = None,
+                 engine_config: ModelConfig | None = None):
         if engine_mode not in ("real", "analytic"):
             raise ValueError(f"engine_mode must be real|analytic, "
                              f"got {engine_mode!r}")
+        if engine_config is not None and engine_mode != "real":
+            raise ValueError("engine_config sets the real engines' model; "
+                             "analytic engines price MODEL_CLASSES")
         self.rng = np.random.default_rng(seed)
         self.vocab = vocab
         self.engine_mode = engine_mode
+        # every real engine runs this model when given (else one derived
+        # from its profile's MODEL_CLASSES entry), placed round-robin over
+        # the visible devices in creation order (`agent_device`)
+        self.engine_config = engine_config
+        self._n_real = 0
         self.telemetry = TelemetryTracker()
         self.evaluator = SimulatedSkillEvaluator(seed=seed + 1)
         self.quarantine_cooldown = quarantine_cooldown
@@ -153,11 +175,14 @@ class SimCluster:
                 speed=prof.speed, cache_slots=cache_slots or prof.cache_slots,
                 max_new_tokens=max_new_tokens)
         else:
-            cfg = _engine_config(prof.model_class, self.vocab)
+            cfg = self.engine_config or _engine_config(prof.model_class,
+                                                       self.vocab)
             engine = AgentEngine(
                 cfg, seed=eng_seed, speed=prof.speed,
                 cache_slots=cache_slots or prof.cache_slots,
-                max_new_tokens=max_new_tokens)
+                max_new_tokens=max_new_tokens,
+                device=agent_device(self._n_real, jax.devices()))
+            self._n_real += 1
         info = AgentInfo(
             agent_id=prof.agent_id,
             prices=TokenPrices(prof.price_miss, prof.price_hit, prof.price_out),

@@ -70,6 +70,7 @@ def _shared_fns(cfg: ModelConfig, max_len: int):
         model = build_model(cfg)
         _SHARED[key] = {
             "model": model,
+            "init": jax.jit(model.init),
             "prefill": jax.jit(
                 lambda p, b: model.prefill(p, {**b, "max_len": max_len})),
             "decode": jax.jit(model.decode_step),
@@ -84,11 +85,18 @@ class AgentEngine:
 
     def __init__(self, cfg: ModelConfig, *, seed: int = 0, speed: float = 1.0,
                  cache_slots: int = 6, max_len: int = 1024,
-                 max_new_tokens: int = 8):
+                 max_new_tokens: int = 8, device=None):
         self.cfg = cfg
         shared = _shared_fns(cfg, max_len)
         self.model = shared["model"]
-        self.params = self.model.init(jax.random.PRNGKey(seed))
+        key = jax.random.PRNGKey(seed)
+        # one fused program run where the key is committed: the weights are
+        # born on the agent's device in their own dtype (no float32 staging
+        # copy), and the jitted prefill/extend/decode then run there,
+        # returning caches that live there too
+        self.params = shared["init"](
+            key if device is None else jax.device_put(key, device))
+        self.device = device
         self.speed = speed
         self.cache_slots = cache_slots
         self.max_len = max_len

@@ -625,6 +625,10 @@ class FederatedSimulator:
         }
 
 
+#: Phase-2 backends that run on the accelerator (jitted / Pallas programs)
+DEVICE_SOLVERS = ("dense-jax", "pallas")
+
+
 def build_federation(dialogues, *, n_agents: int, super_hubs: int,
                      arrivals=None, seed: int = 0,
                      engine_mode: str = "analytic",
@@ -642,8 +646,9 @@ def build_federation(dialogues, *, n_agents: int, super_hubs: int,
     `cluster_super_hubs`; each shard gets `shard_seed(seed, k)` (the
     fold_in-style split that makes runs independent of shard advance
     order) and ``max_inflight // S`` of the global admission window.
-    ``parallel="process"`` puts each shard in its own OS process
-    (`ProcessShardHandle`); at S=1 the single inline shard consumes
+    ``parallel="process"`` puts each shard in its own CPU-only OS process
+    (`ProcessShardHandle`), and is refused for real engines and
+    `DEVICE_SOLVERS`, which need the chip the parent holds; at S=1 the single inline shard consumes
     ``dialogues``/``arrivals`` directly — the bit-exact
     `EventSimulator` oracle configuration.  ``fed_kwargs`` pass through
     to `FederatedSimulator` (epoch, spill knobs, shard_schedule, ...).
@@ -651,6 +656,15 @@ def build_federation(dialogues, *, n_agents: int, super_hubs: int,
     from repro.configs.iemas_cluster import agent_profiles
     from repro.distributed.federation import (ProcessShardHandle, ShardSpec,
                                               shard_seed)
+
+    if parallel == "process":
+        solver = (router_kwargs or {}).get("solver", "mcmf")
+        if engine_mode == "real" or solver in DEVICE_SOLVERS:
+            raise ValueError(
+                f"parallel='process' runs each shard in a CPU-only worker; "
+                f"engine_mode={engine_mode!r} with solver={solver!r} needs "
+                f"the device (real engines and {DEVICE_SOLVERS} run inline "
+                f"in the one process that holds the chip)")
 
     profiles = agent_profiles(n_agents, seed=seed)
     supers = cluster_super_hubs([p.domains for p in profiles],

@@ -35,7 +35,7 @@ def test_bid_kernel_bit_parity_with_oracle(seed):
     (single-unit agents) — the kernel must reproduce the oracle across
     that whole quote range.
     """
-    from repro.kernels.ops import auction_bid_op
+    from repro.kernels.auction_bid import auction_bid
     from repro.kernels.ref import auction_bid_ref
 
     rng = np.random.default_rng(seed)
@@ -48,7 +48,7 @@ def test_bid_kernel_bit_parity_with_oracle(seed):
     ask2 = np.where(rng.random(m) < 0.2, big, ask2)  # single-unit agents
     active = rng.random(n) > rng.uniform(0, 1)
     eps = np.float32(rng.uniform(1e-4, 0.5))
-    got = auction_bid_op(W, ask, ask2, active, eps)
+    got = auction_bid(W, ask, ask2, active, eps)
     want = auction_bid_ref(W, ask, ask2, active, eps)
     for g, w, name in zip(got, want, ("best", "winner", "wants")):
         assert np.array_equal(np.asarray(g), np.asarray(w)), \
@@ -57,7 +57,7 @@ def test_bid_kernel_bit_parity_with_oracle(seed):
 
 def test_bid_kernel_parity_degenerate_inputs():
     """Single request / single agent / nobody active / all-zero weights."""
-    from repro.kernels.ops import auction_bid_op
+    from repro.kernels.auction_bid import auction_bid
     from repro.kernels.ref import auction_bid_ref
 
     big = np.float32(np.finfo(np.float32).max / 4)
@@ -72,7 +72,7 @@ def test_bid_kernel_parity_degenerate_inputs():
          np.zeros(7, np.float32), np.ones(3, bool)),   # total ties
     ]
     for W, ask, ask2, active in cases:
-        got = auction_bid_op(W, ask, ask2, active, np.float32(0.1))
+        got = auction_bid(W, ask, ask2, active, np.float32(0.1))
         want = auction_bid_ref(W, ask, ask2, active, np.float32(0.1))
         for g, w in zip(got, want):
             assert np.array_equal(np.asarray(g), np.asarray(w))

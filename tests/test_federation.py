@@ -209,6 +209,50 @@ def test_process_parallel_bit_identical_to_inline():
     assert o2["federation"]["exactly_once"]["ok"]
 
 
+@pytest.mark.parametrize("engine_mode,solver", [
+    ("real", "dense"), ("analytic", "dense-jax"), ("analytic", "pallas")])
+def test_process_mode_refuses_device_work(engine_mode, solver):
+    """Workers are CPU-only: real engines and device solvers need the chip
+    the parent holds, so process mode refuses them before spawning."""
+    dlg = generate(WorkloadSpec("coqa_like", n_dialogues=2, seed=1))
+    with pytest.raises(ValueError, match="CPU-only"):
+        build_federation(dlg, n_agents=4, super_hubs=2, seed=0,
+                         engine_mode=engine_mode,
+                         router_kwargs=dict(solver=solver),
+                         parallel="process")
+
+
+@pytest.mark.parametrize("argv", [
+    ["--solver", "pallas"], ["--solver", "dense-jax"],
+    ["--engine-mode", "real"]])
+def test_serve_cli_refuses_device_work_in_process_mode(argv, monkeypatch):
+    from repro.launch import serve
+
+    monkeypatch.setattr("sys.argv", [
+        "serve", "--sim-mode", "event", "--super-hubs", "2",
+        "--federation-parallel", "process", *argv])
+    with pytest.raises(SystemExit) as err:
+        serve.main()
+    assert err.value.code == 2
+
+
+@pytest.mark.parametrize("parent", ["tpu", None])
+def test_workers_spawn_with_cpu_only_jax(parent, monkeypatch):
+    """The environment a worker is spawned with pins JAX to the CPU, and
+    the parent's own setting is restored afterwards."""
+    import os
+
+    from repro.distributed.federation import _cpu_only_children
+
+    if parent is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", parent)
+    with _cpu_only_children():
+        assert os.environ["JAX_PLATFORMS"] == "cpu"
+    assert os.environ.get("JAX_PLATFORMS") == parent
+
+
 # ------------------------------------------------------- partitioning --
 def test_cluster_super_hubs_positional_ids_and_coverage():
     """Super-hub ids are list positions (shard seeds / rid prefixes key on

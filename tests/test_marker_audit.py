@@ -16,6 +16,7 @@ TIER1_MODULES = {
     "test_auction",
     "test_auction_dense",
     "test_auction_pallas",
+    "test_chip_compile",
     "test_churn_storm",
     "test_column_market",
     "test_dag_workload",
@@ -29,6 +30,7 @@ TIER1_MODULES = {
     "test_mcmf",
     "test_mechanism",
     "test_models",
+    "test_placement",
     "test_predictor_batch",
     "test_reputation_identity",
     "test_routing_fused",

@@ -389,6 +389,8 @@ def test_serve_cli_trace_file(tmp_path, capsys, monkeypatch):
     """--trace-file reaches the event simulator end to end (the arrivals
     pace admission), and DAG workloads are rejected in closed mode."""
     from repro.launch import serve
+    # the CLI's persistent compile cache stays off under test
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
     trace = tmp_path / "arrivals.txt"
     trace.write_text("0.0\n0.4\n")
     monkeypatch.setattr("sys.argv", [
