@@ -1,0 +1,15 @@
+"""Host settlement of the fused routing step per routed batch (ms/batch):
+wall time of the program's ``iemas.fused.settle`` spans (float64 Clarke
+payments in ``materialize_staged`` and ``package_dense``) over the
+window's ``iemas.route_batch`` spans."""
+import loader
+
+program = loader.module(loader.BENCH / "trace" / "program.py")
+
+
+def read(ctx):
+    found = program.spans(ctx)
+    batches = len(program.named(found or [], "route_batch"))
+    if not batches or not program.named(found, "fused.settle"):
+        return None
+    return 1e-6 * program.wall_ns(found, "fused.settle") / batches

@@ -1,4 +1,4 @@
-"""Fused vs staged routing overhead, 16 -> 128 agents, one hub.
+"""Fused vs staged routing wall-clock, 16 -> 128 agents, one hub.
 
 The ISSUE-9 tentpole measurement: does fusing the whole per-batch routing
 step (ledger gather, Eq.-4 LCP affinity, Eq.-5 Hoeffding descent, Eq.-1
@@ -13,20 +13,20 @@ same single-hub warm-started cell three ways::
 
 Every cell runs TWICE on the same cluster + router: a reduced warmup pass
 populates the pow-2 shape-bucket jit caches and the predictor state, then
-the full measured pass reports steady-state routing overhead so the fused
-path's one-time XLA compile does not masquerade as per-batch cost.  Fused
-rows add the `RoutingProfiler` fused counters: ``host=`` device->host
-materialization boundaries (exactly one per routing step by construction),
-``midsync=`` mid-pipeline host syncs (must stay 0) and ``retrace=``
+the full measured pass reports steady-state routing wall-clock per router
+invocation (``route_ms=``) so the fused path's one-time XLA compile does
+not masquerade as per-batch cost.  Fused rows add the `RoutingProfiler`
+spans and counters: ``device=`` calls of the ``fused.device`` span (the
+device->host materialization boundary, exactly one per routing step by
+construction), ``rounds=`` bid rounds per step and ``retrace=``
 measured-pass program cache growth (bounded by the pow-2 buckets the pass
 visits, not the batch count).
 
-The sweep closes with a per-family comparison line against the staged
-hot-path baseline (docs/benchmarks.md: 4-7% of engine compute up to 128
-agents).  ``--smoke`` runs one reduced cell with the acceptance gates:
-fused overhead <= staged[dense-jax] overhead on the same warmed cell, zero
-mid-pipeline syncs, one host transfer per route call, bounded retraces —
-plus a lockstep fused-vs-staged decision-parity check over heterogeneous
+The sweep closes with a per-family fused/staged routing-time ratio.
+``--smoke`` runs one reduced cell with the acceptance gates: fused routing
+time <= staged[dense-jax] routing time on the same warmed cell, one device
+span per route call, bounded retraces — plus a lockstep fused-vs-staged
+decision-parity check over heterogeneous
 agents with synchronized feedback (identical assignments, payments within
 float32 tolerance; see tests/test_routing_fused.py for the property-test
 version).
@@ -47,8 +47,8 @@ from repro.serving import (EventSimulator, PoissonArrivals, RoutingProfiler,
                            make_router)
 from repro.serving.workload import WORKLOADS
 
-#: same fleet-size grid as benchmarks/serving_scale.py so the overhead
-#: numbers line up with the staged-baseline table in docs/benchmarks.md
+#: same fleet-size grid as benchmarks/serving_scale.py so the routing
+#: times line up with its staged-baseline rows
 SIZES = [(16, 1000), (32, 2000), (64, 5000),
          (SCALE_128.n_agents, SCALE_128.n_dialogues)]
 SMOKE_SIZES = [(16, 150)]
@@ -88,7 +88,7 @@ def run_cell(family: str, n_agents: int, n_dialogues: int, *, solver: str,
 
     Both passes share the cluster and router so the measured pass sees
     populated jit caches (per pow-2 shape bucket) and warmed predictors —
-    the steady-state regime the 4-7% staged baseline was measured in.
+    the steady-state regime the staged baseline rows were measured in.
     The warmup replays the measured pass's own dialogue stream so the two
     passes visit the same shape buckets.
     """
@@ -106,18 +106,22 @@ def run_cell(family: str, n_agents: int, n_dialogues: int, *, solver: str,
 
 
 def _row(tag: str, family: str, n_agents: int, out: dict) -> float:
-    """Emit one CSV row; returns the measured-pass overhead fraction."""
+    """Emit one CSV row; returns the measured pass's routing wall-clock
+    per router invocation (ms)."""
     rep = out["routing"]
-    overhead = rep["overhead_frac"] or 0.0
-    fz = rep["fused"]
-    route_calls = rep["phases"].get("route_batch", {}).get("calls", 0)
+    phases = rep["phases"]
+    route_calls = phases.get("route_batch", {}).get("calls", 0)
+    route_ms = 1e3 * phases.get("route_batch", {}).get("wall_s", 0.0) \
+        / max(route_calls, 1)
+    device = phases.get("fused.device", {}).get("calls", 0)
+    rounds = rep["counters"].get("fused.device.rounds", 0)
     cols = [
-        f"overhead_pct={100.0 * overhead:.2f}",
-        f"engine_s={rep['engine_compute_s']:.1f}",
+        f"route_ms={route_ms:.3f}",
+        f"routing_s={rep['routing_wall_s']:.2f}",
         f"route_calls={route_calls}",
-        f"host={fz['host_transfers']}",
-        f"midsync={fz['mid_pipeline_syncs']}",
-        f"retrace={fz['retraces']}",
+        f"device={device}",
+        f"rounds={rounds / max(device, 1):.1f}",
+        f"retrace={rep['counters'].get('fused.device.retraces', 0)}",
         f"n={out.get('n', 0)}",
         f"kv={out.get('kv_hit_rate', 0.0):.3f}",
         f"done={out.get('dialogues_completed', 0)}",
@@ -125,7 +129,7 @@ def _row(tag: str, family: str, n_agents: int, out: dict) -> float:
     ]
     emit(f"fusedrouting/{family}_a{n_agents}_{tag}",
          out["bench_wall_s"] * 1e6, " ".join(cols))
-    return overhead
+    return route_ms
 
 
 def _lockstep_parity(n_batches: int = 6, m: int = 5, seed: int = 1) -> None:
@@ -205,36 +209,35 @@ def run(smoke: bool = False):
     families = WORKLOADS[:1] if quick else WORKLOADS
     for family in families:
         for n_agents, n_dialogues in sizes:
-            overheads = {}
+            route_ms = {}
             for tag, solver, fused in VARIANTS:
                 out = run_cell(family, n_agents, n_dialogues, solver=solver,
                                fused=fused)
-                overheads[tag] = _row(tag, family, n_agents, out)
+                route_ms[tag] = _row(tag, family, n_agents, out)
                 rep = out["routing"]
                 assert not out["truncated"], f"{tag} cell truncated"
+                device = rep["phases"].get("fused.device", {}).get("calls", 0)
                 if fused:
-                    fz = rep["fused"]
                     route_calls = rep["phases"]["route_batch"]["calls"]
-                    assert fz["mid_pipeline_syncs"] == 0, \
-                        f"{fz['mid_pipeline_syncs']} mid-pipeline host syncs"
-                    assert fz["host_transfers"] == route_calls, \
-                        f"{fz['host_transfers']} host transfers over " \
-                        f"{route_calls} route calls (want exactly 1 each)"
-                    assert fz["retraces"] <= RETRACE_BOUND, \
-                        f"{fz['retraces']} measured-pass retraces > " \
+                    retraces = rep["counters"].get("fused.device.retraces", 0)
+                    assert device == route_calls, \
+                        f"{device} device spans over {route_calls} route " \
+                        f"calls (want exactly 1 each)"
+                    assert retraces <= RETRACE_BOUND, \
+                        f"{retraces} measured-pass retraces > " \
                         f"{RETRACE_BOUND} (pow-2 bucketing regressed?)"
                 else:
-                    assert rep["fused"]["host_transfers"] == 0
+                    assert device == 0
             if smoke:
-                assert overheads["fused[dense-jax]"] \
-                    <= overheads["staged[dense-jax]"], \
-                    f"fused overhead {overheads['fused[dense-jax]']:.4f} " \
-                    f"above staged {overheads['staged[dense-jax]']:.4f}"
-            ratio = (overheads["fused[dense-jax]"]
-                     / max(overheads["staged[dense-jax]"], 1e-12))
+                assert route_ms["fused[dense-jax]"] \
+                    <= route_ms["staged[dense-jax]"], \
+                    f"fused routing {route_ms['fused[dense-jax]']:.3f} ms " \
+                    f"above staged {route_ms['staged[dense-jax]']:.3f} ms"
+            ratio = (route_ms["fused[dense-jax]"]
+                     / max(route_ms["staged[dense-jax]"], 1e-12))
             print(f"fusedrouting/{family}_a{n_agents}_speedup,0.0,"
-                  f"fused/staged_overhead={ratio:.3f} "
-                  f"staged_dense_pct={100 * overheads['staged[dense]']:.2f}",
+                  f"fused/staged_route_ms={ratio:.3f} "
+                  f"staged_dense_ms={route_ms['staged[dense]']:.3f}",
                   flush=True)
     _lockstep_parity()
 

@@ -1,27 +1,25 @@
-"""Serving-scale sweep: routing overhead vs engine compute, 16 -> 128 agents.
+"""Serving-scale sweep: routing wall-clock per batch, 16 -> 128 agents.
 
-The ISSUE-5 tentpole measurement (ROADMAP: "scale the serving simulation to
-100+ agents / 10k dialogues and profile where routing overhead crosses 10%
-of engine compute").  For each workload family the event-driven open-loop
-simulator (`repro.serving.simulator.EventSimulator`) drives a Poisson
-dialogue stream through an analytic-engine cluster while a
-`RoutingProfiler` attributes the router's real wall-clock per phase
-(Phase-1 predict, Phase-2 solve per backend, cross-hub spill, price-book
-ops, Phase-4 feedback) against the *simulated engine compute* the cluster
-reports.  Per cell it emits::
+The scale measurement (ROADMAP: "scale the serving simulation to
+100+ agents / 10k dialogues and profile the router").  For each workload
+family the event-driven open-loop simulator
+(`repro.serving.simulator.EventSimulator`) drives a Poisson dialogue stream
+through an analytic-engine cluster while a `RoutingProfiler` times the
+router's real wall-clock per phase (Phase-1 predict, Phase-2 solve per
+backend, cross-hub spill, price-book ops, Phase-4 feedback).  Routing time
+is reported in absolute milliseconds, per router invocation (per
+completion for feedback), not as a share of the analytic engines' virtual
+seconds: a faster engine must not read as a slower router.  Per cell it
+emits::
 
     servingscale/<family>_a<agents>_d<dialogues>,<wall us>,
-        overhead_pct=..  p1_pct=..  p2_pct=..  spill_pct=..  book_pct=..
-        fb_pct=..  engine_s=..  route_calls=..  n=..  kv=..  ...
+        route_ms=..  p1_ms=..  p2_ms=..  spill_ms=..  book_ms=..
+        fb_us=..  routing_s=..  route_calls=..  n=..  kv=..  ...
 
-and after each family a crossover line naming the smallest fleet size where
-total routing overhead reached 10% of engine compute (or reporting that it
-never did — measured: the dense hub-sharded warm-started hot path stays at
-4–7% up to 128 agents / 10k dialogues; see docs/benchmarks.md for the
-table).  Pass ``--oracle`` to add an exact-MCMF row at the smallest size:
-at micro-batch markets even the Python oracle is affordable (~1.3%) — its
-blowup is market-size-driven (`mcmf_scaling.py`), which is exactly what
-hub sharding keeps bounded.
+Pass ``--oracle`` to add an exact-MCMF row at the smallest size: at
+micro-batch markets even the Python oracle is affordable — its blowup is
+market-size-driven (`mcmf_scaling.py`), which is exactly what hub sharding
+keeps bounded.
 
 Acceptance gate: the full run completes the 128-agent / 10k-dialogue cell
 per family (all dialogues finish, nothing truncated).  ``--smoke`` runs one
@@ -47,7 +45,13 @@ from repro.serving.workload import WORKLOADS
 SIZES = [(16, 1000), (32, 2000), (64, 5000),
          (SCALE_128.n_agents, SCALE_128.n_dialogues)]
 SMOKE_SIZES = [(16, 150)]
-CROSSOVER = 0.10
+#: smoke regression bound on the routing wall-clock per router invocation
+#: (ms): an order of magnitude above the smoke cell's measured time on a
+#: CPU container, so it catches a blowup, not noise
+ROUTE_MS_BOUND = 150.0
+#: the federated smoke cell's bound on routing wall-clock per completed
+#: request (us), with the same headroom
+FED_ROUTE_US_BOUND = 30_000.0
 
 #: federation study grid: (n_agents, n_dialogues, super_hubs); the first
 #: cell — the single-heap sweep's flagship 128 × 10k size — also runs
@@ -87,33 +91,40 @@ def run_cell(family: str, n_agents: int, n_dialogues: int, *,
     return out
 
 
-def _pct(report: dict, prefix: str) -> float:
-    """Summed frac-of-engine (as %) over phases starting with ``prefix``.
+def _ms_per(report: dict, prefix: str, per: str = "route_batch") -> float:
+    """Summed wall-clock (ms) of the phases starting with ``prefix``, per
+    call of the ``per`` phase (0 when it never ran)."""
+    calls = report["phases"].get(per, {}).get("calls", 0)
+    wall = sum(p["wall_s"] for name, p in report["phases"].items()
+               if name.startswith(prefix))
+    return 1e3 * wall / calls if calls else 0.0
 
-    ``frac_of_engine`` is None on zero-engine-compute runs (see
-    `RoutingProfiler.report`); such phases contribute 0 here so a
-    degenerate cell still emits a diagnosable row.
-    """
-    return 100.0 * sum(p["frac_of_engine"] or 0.0
-                       for name, p in report["phases"].items()
-                       if name.startswith(prefix))
+
+def _wall(report: dict, name: str) -> float:
+    """Wall-clock seconds of one phase (0 when it never ran)."""
+    return report["phases"].get(name, {}).get("wall_s", 0.0)
+
+
+def _us_per_req(out: dict) -> float:
+    """Routing wall-clock (router invocations, feedback and, federated,
+    the epoch boundaries) per completed request, in microseconds."""
+    return 1e6 * out["routing"]["routing_wall_s"] / max(out.get("n", 0), 1)
 
 
 def _row(family: str, n_agents: int, n_dialogues: int, out: dict) -> float:
-    """Emit one CSV row; returns the total overhead fraction (0 when no
-    engine compute was simulated — a degenerate cell)."""
+    """Emit one CSV row; returns the routing wall-clock per router
+    invocation (ms)."""
     rep = out["routing"]
-    overhead = rep["overhead_frac"] or 0.0
-    route_calls = rep["phases"].get("route_batch", {}).get("calls", 0)
+    route_ms = _ms_per(rep, "route_batch")
     cols = [
-        f"overhead_pct={100.0 * overhead:.2f}",
-        f"p1_pct={_pct(rep, 'phase1_predict'):.2f}",
-        f"p2_pct={_pct(rep, 'phase2_solve'):.2f}",
-        f"spill_pct={_pct(rep, 'phase2_spill'):.2f}",
-        f"book_pct={_pct(rep, 'price_book'):.3f}",
-        f"fb_pct={_pct(rep, 'phase4_feedback'):.2f}",
-        f"engine_s={rep['engine_compute_s']:.1f}",
-        f"route_calls={route_calls}",
+        f"route_ms={route_ms:.3f}",
+        f"p1_ms={_ms_per(rep, 'phase1_predict'):.3f}",
+        f"p2_ms={_ms_per(rep, 'phase2_solve'):.3f}",
+        f"spill_ms={_ms_per(rep, 'phase2_spill'):.3f}",
+        f"book_ms={_ms_per(rep, 'price_book'):.4f}",
+        f"fb_us={1e3 * _ms_per(rep, 'phase4_feedback', 'phase4_feedback'):.1f}",
+        f"routing_s={rep['routing_wall_s']:.2f}",
+        f"route_calls={rep['phases'].get('route_batch', {}).get('calls', 0)}",
         f"n={out.get('n', 0)}",
         f"kv={out.get('kv_hit_rate', 0.0):.3f}",
         f"lat_p95_ms={out.get('latency_ms_p95', 0.0):.1f}",
@@ -124,7 +135,7 @@ def _row(family: str, n_agents: int, n_dialogues: int, out: dict) -> float:
     ]
     emit(f"servingscale/{family}_a{n_agents}_d{n_dialogues}",
          out["bench_wall_s"] * 1e6, " ".join(cols))
-    return overhead
+    return route_ms
 
 
 def _incremental_study(family: str, n_agents: int, n_dialogues: int,
@@ -215,11 +226,11 @@ def _fed_row(family: str, n_agents: int, n_dialogues: int, s: int,
     eo = fed["exactly_once"]
     wf = out["accounts"]["welfare_realized"] / max(out.get("n", 1), 1)
     cols = [
-        f"overhead_pct={100.0 * (rep['overhead_frac'] or 0.0):.2f}",
-        f"gossip_pct={_pct(rep, 'federation_gossip'):.3f}",
-        f"fed_spill_pct={_pct(rep, 'federation_spill'):.3f}",
-        f"migrate_pct={_pct(rep, 'federation_migrate'):.3f}",
-        f"engine_s={rep['engine_compute_s']:.1f}",
+        f"route_us_per_req={_us_per_req(out):.1f}",
+        f"gossip_ms={1e3 * _wall(rep, 'federation_gossip'):.2f}",
+        f"fed_spill_ms={1e3 * _wall(rep, 'federation_spill'):.2f}",
+        f"migrate_ms={1e3 * _wall(rep, 'federation_migrate'):.2f}",
+        f"routing_s={rep['routing_wall_s']:.2f}",
         f"epochs={out['epochs']}",
         f"spilled={fed['spill_migrated']}/{fed['spill_candidates']}",
         f"stale_max={fed['gossip']['max_staleness_epochs']}",
@@ -239,7 +250,7 @@ def _gate_federation(out: dict, n_dialogues: int, super_hubs: int) -> None:
     """Structural federation gates: exactly-once settlement verified by
     ledger replay, nothing lost or double-settled, migrations balanced,
     spill never consumed a digest staler than one epoch, and the epoch
-    boundaries' own cost stayed inside the routing-overhead bound."""
+    boundaries' own cost stayed inside the routing wall-clock bound."""
     eo = out["federation"]["exactly_once"]
     assert eo["ok"], f"exactly-once audit failed: {eo}"
     assert eo["ledger_replay_ok"] and eo["ledgers_attached"] == super_hubs
@@ -249,9 +260,10 @@ def _gate_federation(out: dict, n_dialogues: int, super_hubs: int) -> None:
         == n_dialogues
     assert not out["truncated"], "federation cell truncated"
     assert out["federation"]["gossip"]["max_staleness_epochs"] <= 1
-    assert 0 < out["routing"]["overhead_frac"] < 0.5, \
-        f"routing+boundary overhead {out['routing']['overhead_frac']:.3f} " \
-        f"out of the (0, 0.5) regression bound"
+    us = _us_per_req(out)
+    assert 0 < us < FED_ROUTE_US_BOUND, \
+        f"routing+boundary wall-clock {us:.1f} us per request out of the " \
+        f"(0, {FED_ROUTE_US_BOUND}) regression bound"
 
 
 def run_federation(smoke: bool = False):
@@ -293,31 +305,26 @@ def run_federation(smoke: bool = False):
 
 
 def run(smoke: bool = False, oracle: bool = False):
-    """Sweep the (family x fleet-size) grid and report 10% crossovers."""
+    """Sweep the (family x fleet-size) grid."""
     quick = smoke or QUICK
     sizes = SMOKE_SIZES if quick else SIZES
     families = WORKLOADS[:1] if smoke else WORKLOADS
     for family in families:
-        crossover_at = None
         for n_agents, n_dialogues in sizes:
             out = run_cell(family, n_agents, n_dialogues)
-            overhead = _row(family, n_agents, n_dialogues, out)
-            if crossover_at is None and overhead >= CROSSOVER:
-                crossover_at = n_agents
+            route_ms = _row(family, n_agents, n_dialogues, out)
             if smoke:
                 # structural gates (size-independent correctness)
                 rep = out["routing"]
                 assert out["dialogues_completed"] == n_dialogues, \
                     f"{out['dialogues_completed']}/{n_dialogues} completed"
                 assert not out["truncated"], "smoke run truncated"
-                assert rep["engine_compute_s"] > 0
-                # regression bound on the routing-overhead fraction: the
-                # measured smoke cell sits well under 10% (docs/benchmarks
-                # table: 4-7% up to 128 agents); 0.5 gives noisy-CI headroom
-                # while still catching an order-of-magnitude regression
-                assert 0 < rep["overhead_frac"] < 0.5, \
-                    f"routing overhead {rep['overhead_frac']:.3f} out of " \
-                    f"the (0, 0.5) regression bound"
+                # regression bound on the routing wall-clock per router
+                # invocation (ROUTE_MS_BOUND: an order of magnitude of
+                # headroom over the measured smoke cell)
+                assert 0 < route_ms < ROUTE_MS_BOUND, \
+                    f"routing {route_ms:.3f} ms per batch out of the " \
+                    f"(0, {ROUTE_MS_BOUND}) regression bound"
                 # the event loop never invokes the router without work
                 assert rep["empty_route_calls"] == 0
                 assert rep["route_requests"] >= out["dispatched_requests"]
@@ -341,10 +348,6 @@ def run(smoke: bool = False, oracle: bool = False):
             out = run_cell(family, n_agents, max(200, n_dialogues // 5),
                            solver="mcmf")
             _row(f"{family}_mcmf", n_agents, max(200, n_dialogues // 5), out)
-        tag = (f"crossover at {crossover_at} agents" if crossover_at
-               else f"no >= {100 * CROSSOVER:.0f}% crossover up to "
-                    f"{sizes[-1][0]} agents")
-        print(f"servingscale/{family}_crossover,0.0,{tag}", flush=True)
 
 
 def main():

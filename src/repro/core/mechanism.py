@@ -132,8 +132,8 @@ class IEMASRouter:
         self.valuation = valuation or ValuationConfig()
         self.payment_mode = payment_mode
         # optional serving-layer RoutingProfiler (duck-typed: anything with a
-        # phase(name) context manager); attributes per-phase wall-clock for
-        # the overhead-crossover study — None keeps every section a no-op
+        # phase(name, **stats) context manager); per-phase wall clock and
+        # spans — None keeps every section a no-op
         self.profiler = None
         self.solver = solver
         self.spill = spill
@@ -627,6 +627,16 @@ class IEMASRouter:
         return decisions
 
     # ---------------- Phase 4: feedback ----------------
+    def promised_hit(self, request_id: str) -> int:
+        """Prompt tokens the Eq.-4 affinity of a pending request's matched
+        pair promised from the agent's cache: round(affinity x prompt
+        length) of its feature row (0 once the request is retired)."""
+        entry = self._pending.get(request_id)
+        if entry is None:
+            return 0
+        x = entry[0]
+        return round(x.affinity * x.prompt_len)
+
     def on_complete(self, request_id: str, obs: CompletionObs) -> None:
         """Phase 4: predictor/ledger updates + market accounting (or the
         fault path: quarantine, no payment) for one completed request."""

@@ -62,6 +62,7 @@ from repro.core.solvers.dense_common import (THETA, check_start_prices,
                                              materialize_staged,
                                              package_dense, warm_round_budget)
 from repro.core.solvers.dense_np import _price_grid
+from repro.utils.timing import phase_scope
 
 #: solver backends whose bidding loop can compose inside the fused program
 #: (both ride `dense_jax._build_jax_solver`; pallas swaps the bid round).
@@ -209,132 +210,138 @@ def _build_program(warm: bool, has_parents: bool, budget: int,
         fdt = p0.dtype
         nb, mb = dom.shape
 
-        # ---- (a) Eq.-4 affinity: arena gather + cumprod-of-equality LCP
-        def lcp_scores(rows_ix, prompts, plens):
-            led = arena[rows_ix]                       # (B, mb, L)
-            llen = alen[rows_ix]
-            eq = (prompts[:, None, :] == led).astype(jnp.int32)
-            raw = jnp.cumprod(eq, axis=-1).sum(-1)
-            lcp = jnp.minimum(raw, jnp.minimum(plens[:, None], llen))
-            pl1 = jnp.maximum(plens[:, None], 1).astype(fdt)
-            sc = lcp.astype(fdt) / pl1
-            # recurrent agents: exact-extension-only cache reuse
-            full_prev = (lcp == llen) & (llen > 0)
-            return jnp.where(ext[None, :],
-                             jnp.where(full_prev, llen.astype(fdt) / pl1,
-                                       0.0), sc)
+        # the three stages carry stable names in the compiled program
+        # (op metadata), so a device trace can attribute its operations
+        with jax.named_scope("iemas.fused/affinity"):
+            # ---- (a) Eq.-4 affinity: arena gather + cumprod-of-equality LCP
+            def lcp_scores(rows_ix, prompts, plens):
+                led = arena[rows_ix]                       # (B, mb, L)
+                llen = alen[rows_ix]
+                eq = (prompts[:, None, :] == led).astype(jnp.int32)
+                raw = jnp.cumprod(eq, axis=-1).sum(-1)
+                lcp = jnp.minimum(raw, jnp.minimum(plens[:, None], llen))
+                pl1 = jnp.maximum(plens[:, None], 1).astype(fdt)
+                sc = lcp.astype(fdt) / pl1
+                # recurrent agents: exact-extension-only cache reuse
+                full_prev = (lcp == llen) & (llen > 0)
+                return jnp.where(ext[None, :],
+                                 jnp.where(full_prev, llen.astype(fdt) / pl1,
+                                           0.0), sc)
 
-        o = jnp.where(keep, lcp_scores(lrows, pmat, plen), 0.0)
-        if has_parents:
-            # precedence credit: candidate (row, parent-session) pairs were
-            # flattened on host; fold their best affinity into o by a
-            # scatter-max (cj == nb marks padding, dropped by mode="drop")
-            cjc = jnp.clip(cj, 0, nb - 1)
-            cred = jnp.where(ckeep,
-                             lcp_scores(crows, pmat[cjc], plen[cjc]), 0.0)
-            o = o.at[cj].max(cred, mode="drop")
+            o = jnp.where(keep, lcp_scores(lrows, pmat, plen), 0.0)
+            if has_parents:
+                # precedence credit: candidate (row, parent-session) pairs were
+                # flattened on host; fold their best affinity into o by a
+                # scatter-max (cj == nb marks padding, dropped by mode="drop")
+                cjc = jnp.clip(cj, 0, nb - 1)
+                cred = jnp.where(ckeep,
+                                 lcp_scores(crows, pmat[cjc], plen[cjc]), 0.0)
+                o = o.at[cj].max(cred, mode="drop")
 
-        # ---- (b) Eq.-5 feature tensor, assembled on device
-        util = a_inflight / jnp.maximum(1.0, caps_f)
+        with jax.named_scope("iemas.fused/predict"):
+            # ---- (b) Eq.-5 feature tensor, assembled on device
+            util = a_inflight / jnp.maximum(1.0, caps_f)
 
-        def bc(v):
-            return jnp.broadcast_to(v, (nb, mb))
+            def bc(v):
+                return jnp.broadcast_to(v, (nb, mb))
 
-        X = jnp.stack([
-            bc(plen.astype(fdt)[:, None]), bc(turns[:, None]), o,
-            bc(router_scalars[0]), bc(router_scalars[1]),
-            bc(a_inflight[None, :]), bc(a_rps[None, :]),
-            bc(caps_f[None, :]), bc(util[None, :]), dom,
-        ], axis=-1)
+            X = jnp.stack([
+                bc(plen.astype(fdt)[:, None]), bc(turns[:, None]), o,
+                bc(router_scalars[0]), bc(router_scalars[1]),
+                bc(a_inflight[None, :]), bc(a_rps[None, :]),
+                bc(caps_f[None, :]), bc(util[None, :]), dom,
+            ], axis=-1)
 
-        # ---- (c) Phase-1 descend over the stacked forests + prior blend
-        flat = X.reshape(nb * mb, N_FEATURES)
-        rows = jnp.arange(nb * mb)
-        col = jnp.arange(nb * mb, dtype=jnp.int32) % mb
+            # ---- (c) Phase-1 descend over the stacked forests + prior blend
+            flat = X.reshape(nb * mb, N_FEATURES)
+            rows = jnp.arange(nb * mb)
+            col = jnp.arange(nb * mb, dtype=jnp.int32) % mb
 
-        def desc(forest, depth):
-            feature, threshold, left, right, value, roots = forest
+            def desc(forest, depth):
+                feature, threshold, left, right, value, roots = forest
 
-            def body(_, cur):
-                f = feature[cur]
-                internal = f >= 0
-                go_left = flat[rows, jnp.where(internal, f, 0)] \
-                    <= threshold[cur]
-                nxt = jnp.where(go_left, left[cur], right[cur])
-                return jnp.where(internal, nxt, cur)
+                def body(_, cur):
+                    f = feature[cur]
+                    internal = f >= 0
+                    go_left = flat[rows, jnp.where(internal, f, 0)] \
+                        <= threshold[cur]
+                    nxt = jnp.where(go_left, left[cur], right[cur])
+                    return jnp.where(internal, nxt, cur)
 
-            return value[lax.fori_loop(0, depth, body,
-                                       roots[col])].reshape(nb, mb)
+                return value[lax.fori_loop(0, depth, body,
+                                           roots[col])].reshape(nb, mb)
 
-        raw_lat = desc(f_lat, dl)
-        raw_cst = desc(f_cst, dc)
-        raw_q = desc(f_q, dq)
+            raw_lat = desc(f_lat, dl)
+            raw_cst = desc(f_cst, dc)
+            raw_q = desc(f_q, dq)
 
-        # transcription of predictor._blend_with_prior (same op order)
-        (lpt, lb_, miss, hit, out_, ewma, n_obs, warm_n, prior_q, rep,
-         expl) = blend
-        pl_, aff, util2 = X[..., 0], X[..., 2], X[..., 8]
-        uncached = pl_ * (1.0 - aff)
-        prior_lat = (lb_ + lpt * uncached) * (1.0 + util2)
-        npmt = jnp.trunc(pl_)
-        nhit = aff * npmt
-        prior_cst = miss * (npmt - nhit) + hit * nhit + out_ * ewma
-        wgt = jnp.minimum(1.0, n_obs / 60.0) * rep
-        lat = (1.0 - wgt) * prior_lat + wgt * jnp.maximum(0.0, raw_lat)
-        cst = (1.0 - wgt) * prior_cst + wgt * jnp.maximum(0.0, raw_cst)
-        cold = n_obs < warm_n
-        lat = jnp.where(cold, prior_lat, lat)
-        cst = jnp.where(cold, prior_cst, cst)
-        qual = jnp.where(cold, prior_q * rep,
-                         jnp.clip(raw_q, 0.0, 1.0) * rep)
-        # optimism bonus (predictor._optimism): applied only where the
-        # per-agent explore knob is nonzero, so the default-0 fleet keeps
-        # the exact pre-bonus values (no min-clamp is ever taken)
-        qual = jnp.where(expl != 0.0,
-                         jnp.minimum(1.0, qual
-                                     + expl / jnp.sqrt(1.0 + n_obs)),
-                         qual)
+            # transcription of predictor._blend_with_prior (same op order)
+            (lpt, lb_, miss, hit, out_, ewma, n_obs, warm_n, prior_q, rep,
+             expl) = blend
+            pl_, aff, util2 = X[..., 0], X[..., 2], X[..., 8]
+            uncached = pl_ * (1.0 - aff)
+            prior_lat = (lb_ + lpt * uncached) * (1.0 + util2)
+            npmt = jnp.trunc(pl_)
+            nhit = aff * npmt
+            prior_cst = miss * (npmt - nhit) + hit * nhit + out_ * ewma
+            wgt = jnp.minimum(1.0, n_obs / 60.0) * rep
+            lat = (1.0 - wgt) * prior_lat + wgt * jnp.maximum(0.0, raw_lat)
+            cst = (1.0 - wgt) * prior_cst + wgt * jnp.maximum(0.0, raw_cst)
+            cold = n_obs < warm_n
+            lat = jnp.where(cold, prior_lat, lat)
+            cst = jnp.where(cold, prior_cst, cst)
+            qual = jnp.where(cold, prior_q * rep,
+                             jnp.clip(raw_q, 0.0, 1.0) * rep)
+            # optimism bonus (predictor._optimism): applied only where the
+            # per-agent explore knob is nonzero, so the default-0 fleet keeps
+            # the exact pre-bonus values (no min-clamp is ever taken)
+            qual = jnp.where(expl != 0.0,
+                             jnp.minimum(1.0, qual
+                                         + expl / jnp.sqrt(1.0 + n_obs)),
+                             qual)
 
-        # ---- Eq.-1 client value -> pruned welfare (valuation.client_value)
-        delta, lscale, vscale = val_cfg[0], val_cfg[1], val_cfg[2]
-        values = vscale * (delta * jnp.clip(qual, 0.0, 1.0)
-                           - (1.0 - delta) * lat / lscale)
-        W = values - cst
-        W = jnp.where(W > 0.0, W, 0.0)
-        W = jnp.where(req_mask[:, None] & agent_mask[None, :], W, 0.0)
+            # ---- Eq.-1 client value -> pruned welfare
+            #      (valuation.client_value)
+            delta, lscale, vscale = val_cfg[0], val_cfg[1], val_cfg[2]
+            values = vscale * (delta * jnp.clip(qual, 0.0, 1.0)
+                               - (1.0 - delta) * lat / lscale)
+            W = values - cst
+            W = jnp.where(W > 0.0, W, 0.0)
+            W = jnp.where(req_mask[:, None] & agent_mask[None, :], W, 0.0)
 
-        # ---- ε schedule as traced scalars (dense_common.jax_eps_final /
-        #      warm_eps0; the staged path computes these on host floats)
-        wmax = jnp.max(jnp.where(counts[None, :] > 0, W, 0.0))
-        anchor = jnp.maximum(wmax, 1.0)
-        eps_final = jnp.maximum(1e-5 * anchor, 64.0 * _EPS32 * anchor)
-        theta = jnp.asarray(THETA, fdt)
-        cold_eps0 = jnp.maximum(wmax / theta, eps_final)
+        with jax.named_scope("iemas.fused/auction"):
+            # ---- ε schedule as traced scalars (dense_common.jax_eps_final /
+            #      warm_eps0; the staged path computes these on host floats)
+            wmax = jnp.max(jnp.where(counts[None, :] > 0, W, 0.0))
+            anchor = jnp.maximum(wmax, 1.0)
+            eps_final = jnp.maximum(1e-5 * anchor, 64.0 * _EPS32 * anchor)
+            theta = jnp.asarray(THETA, fdt)
+            cold_eps0 = jnp.maximum(wmax / theta, eps_final)
 
-        # ---- (d) capacitated-column ε-scaling auction, in-program
-        if warm:
-            # fine schedule iff the seed carries price mass above it
-            # (warm_eps0); fine <= cold_eps0 by construction, so the host
-            # path's min() is already folded in
-            fine = jnp.maximum(wmax / theta ** 3, eps_final)
-            eps0 = jnp.where(p0.max() > fine, fine, cold_eps0)
-            up, ao, uo, rounds = solve_warm(W, counts, p0, eps0, eps_final,
-                                            theta)
-            tripped = rounds >= budget
+            # ---- (d) capacitated-column ε-scaling auction, in-program
+            if warm:
+                # fine schedule iff the seed carries price mass above it
+                # (warm_eps0); fine <= cold_eps0 by construction, so the host
+                # path's min() is already folded in
+                fine = jnp.maximum(wmax / theta ** 3, eps_final)
+                eps0 = jnp.where(p0.max() > fine, fine, cold_eps0)
+                up, ao, uo, rounds = solve_warm(W, counts, p0, eps0, eps_final,
+                                                theta)
+                tripped = rounds >= budget
 
-            def cold_solve(_):
-                return solve_cold(W, counts, jnp.zeros_like(p0), cold_eps0,
-                                  eps_final, theta)
+                def cold_solve(_):
+                    return solve_cold(W, counts, jnp.zeros_like(p0), cold_eps0,
+                                      eps_final, theta)
 
-            def keep(_):
-                return up, ao, uo, rounds
+                def keep(_):
+                    return up, ao, uo, rounds
 
-            up, ao, uo, rounds = lax.cond(tripped, cold_solve, keep,
-                                          operand=None)
-        else:
-            up, ao, uo, rounds = solve_cold(W, counts, p0, cold_eps0,
-                                            eps_final, theta)
-            tripped = jnp.asarray(False)
+                up, ao, uo, rounds = lax.cond(tripped, cold_solve, keep,
+                                              operand=None)
+            else:
+                up, ao, uo, rounds = solve_cold(W, counts, p0, cold_eps0,
+                                                eps_final, theta)
+                tripped = jnp.asarray(False)
         return (lat, cst, qual, values, X, up, ao, uo, rounds, tripped,
                 eps_final, wmax)
 
@@ -393,7 +400,35 @@ class FusedRoutingStep:
         values, X, result)`` — float64 host matrices shaped like the staged
         `_phase1` outputs plus the packaged
         :class:`~repro.core.solvers.base.AuctionResult`.
+
+        Three profiler spans split the call: ``fused.assemble`` (host
+        inputs up to the program call), ``fused.device`` (the program and
+        the one materialization of its outputs; counters ``rounds``,
+        ``warm``, ``fallback``, ``retraces``) and ``fused.settle`` (float64
+        Clarke payments and packaging).
         """
+        prof = getattr(self.router, "profiler", None)
+        n, m = len(requests), len(live)
+        with phase_scope(prof, "fused.assemble"):
+            prog, args, static, counts_np, warm = self._assemble(
+                requests, live, telemetry, caps, start_prices)
+        with phase_scope(prof, "fused.device") as span:
+            out = self._materialize(prog(*args, **static), n, m,
+                                    int(counts_np.max()) if m else 0)
+            c = self.cache_size()
+            span.set(rounds=out["rounds"], warm=warm,
+                     fallback=warm and out["tripped"],
+                     retraces=max(0, c - self._cache_seen))
+            self._cache_seen = c
+        with phase_scope(prof, "fused.settle"):
+            result = self._settle(out, caps, counts_np, warm, n)
+        return (out["lat"], out["cst"], out["qual"], out["values"], out["X"],
+                result)
+
+    def _assemble(self, requests, live, telemetry, caps, start_prices):
+        """Host-side inputs of one fused call: mirror syncs, request
+        packing, the blend table and the warm-start price grid.  Returns
+        ``(program, args, static args, column counts, warm)``."""
         r = self.router
         n, m = len(requests), len(live)
         nb, mb = pow2_bucket(n), pow2_bucket(m)
@@ -402,8 +437,8 @@ class FusedRoutingStep:
         ledger = r.ledger
         store = ledger.store
 
-        # ---- host-side assembly: tiny index/param arrays only (the token
-        #      payloads and every O(n*m) operation stay on device)
+        # ---- tiny index/param arrays only (the token payloads and every
+        #      O(n*m) operation stay on device)
         self.ledger_mirror.sync()
         L = store.width
         lrows = np.zeros((nb, mb), np.int32)
@@ -502,44 +537,47 @@ class FusedRoutingStep:
             if warm else 0
 
         prog = self._program(warm, has_parents, budget)
-        out = prog(self.ledger_mirror.tokens, self.ledger_mirror.lens,
-                   lrows, pmat, plen, keep, crows, cj, ckeep, turns, dom,
-                   req_mask, router_scalars, a_inflight, a_rps, caps_f, ext,
-                   agent_mask, blend, f_lat, f_cst, f_q, val_cfg, counts,
-                   grid, dl=dl, dc=dc, dq=dq)
+        args = (self.ledger_mirror.tokens, self.ledger_mirror.lens, lrows,
+                pmat, plen, keep, crows, cj, ckeep, turns, dom, req_mask,
+                router_scalars, a_inflight, a_rps, caps_f, ext, agent_mask,
+                blend, f_lat, f_cst, f_q, val_cfg, counts, grid)
+        return prog, args, dict(dl=dl, dc=dc, dq=dq), counts_np, warm
 
-        # ---- the batch's ONE device->host boundary: RouteDecision inputs
-        #      materialize here, after the auction settled
+    @staticmethod
+    def _materialize(out, n: int, m: int, cmax: int) -> dict:
+        """The batch's ONE device->host boundary: every output the
+        RouteDecisions need, after the auction settled, cut to the
+        batch's own n x m market."""
         (lat, cst, qual, values, X, up, ao, uo, rounds, tripped, eps_f,
          wmax) = out
-        lat = np.asarray(lat, np.float64)[:n, :m]
-        cst = np.asarray(cst, np.float64)[:n, :m]
-        qual = np.asarray(qual, np.float64)[:n, :m]
-        values = np.asarray(values, np.float64)[:n, :m]
-        X = np.asarray(X, np.float64)[:n, :m]
-        rounds_h = int(rounds)
-        prof = getattr(r, "profiler", None)
-        if prof is not None and hasattr(prof, "note_fused_step"):
-            c = self.cache_size()
-            prof.note_fused_step(host_transfers=1, mid_syncs=0,
-                                 retraces=max(0, c - self._cache_seen))
-            self._cache_seen = c
+        return {
+            "lat": np.asarray(lat, np.float64)[:n, :m],
+            "cst": np.asarray(cst, np.float64)[:n, :m],
+            "qual": np.asarray(qual, np.float64)[:n, :m],
+            "values": np.asarray(values, np.float64)[:n, :m],
+            "X": np.asarray(X, np.float64)[:n, :m],
+            "up": np.asarray(up, np.float64)[:m, :cmax],
+            "ao": np.asarray(ao)[:n], "uo": np.asarray(uo)[:n],
+            "rounds": int(rounds), "tripped": bool(tripped),
+            "eps_final": float(eps_f), "wmax": float(wmax)}
 
-        # host packaging — same helpers as the staged backends, float64
-        # weights recomputed host-side for Clarke payments (auction._prune)
-        w64 = values - cst
+    def _settle(self, out: dict, caps, counts_np, warm: bool, n: int):
+        """Host packaging — same helpers as the staged backends, float64
+        weights recomputed host-side for Clarke payments
+        (auction._prune)."""
+        r = self.router
+        cst = out["cst"]
+        w64 = out["values"] - cst
         w64 = np.where(w64 > 0.0, w64, 0.0)
-        if n == 0 or K == 0 or float(wmax) <= 0.0:
+        if n == 0 or int(counts_np.sum()) == 0 or out["wmax"] <= 0.0:
             dres = empty_result(n, counts_np)
         else:
-            if rounds_h >= self.max_rounds:
+            if out["rounds"] >= self.max_rounds:
                 raise RuntimeError(
                     f"dense auction (fused/{r.solver}) failed to converge "
-                    f"in {self.max_rounds} rounds (n={n}, m={m})")
+                    f"in {self.max_rounds} rounds (n={n}, m={len(caps)})")
             dres = materialize_staged(
-                w64, counts_np, np.asarray(up, np.float64)[:m, :cmax],
-                np.asarray(ao)[:n], np.asarray(uo)[:n], rounds_h,
-                float(eps_f), warm_started=warm,
-                fallback=warm and bool(tripped))
-        result = package_dense(r.solver, w64, cst, caps, dres)
-        return lat, cst, qual, values, X, result
+                w64, counts_np, out["up"], out["ao"], out["uo"],
+                out["rounds"], out["eps_final"], warm_started=warm,
+                fallback=warm and out["tripped"])
+        return package_dense(r.solver, w64, cst, caps, dres)

@@ -9,8 +9,10 @@ Two serving loops:
   * ``--sim-mode event`` — the event-driven open-loop
     `repro.serving.simulator.EventSimulator`: Poisson arrivals at
     ``--arrival-rate``, streaming admission (``--max-inflight``), analytic
-    engines by default, and a `RoutingProfiler` report attributing routing
-    wall-clock per phase against simulated engine compute.  Scale example::
+    engines by default, and a `RoutingProfiler` report: absolute routing
+    wall-clock, wall-clock and calls per phase, and the span counters (bid
+    rounds, warm fallbacks, engine modes, cache hits, evictions).  Scale
+    example::
 
         python -m repro.launch.serve --sim-mode event --agents 128 \\
             --n-dialogues 10000 --arrival-rate 60 --hubs 8 --solver dense
@@ -78,7 +80,7 @@ def main():
                     help="closed: lockstep run_workload oracle loop; "
                          "event: open-loop event-driven simulator "
                          "(repro.serving.simulator) with per-phase routing "
-                         "overhead attribution")
+                         "wall-clock attribution")
     ap.add_argument("--arrival-rate", type=float, default=None,
                     help="event mode: Poisson dialogue arrivals per virtual "
                          "second (default: synchronous, all at t=0)")

@@ -21,6 +21,11 @@ def _res(x):
 
 
 # ---------------- dense / moe attention blocks ----------------
+#: stable names of the two halves of an attention block in the compiled
+#: programs (op metadata), so a device trace can attribute the engine's
+#: operations to attention or MLP
+ATTN_SCOPE, MLP_SCOPE = "iemas.engine/attention", "iemas.engine/mlp"
+
 
 def attn_block_init(key, cfg, dtype, *, ffn_kind: str, d_ff: int | None = None):
     """ffn_kind: dense | moe."""
@@ -50,32 +55,37 @@ def attn_block_axes(cfg, *, ffn_kind: str):
 
 def attn_block_parallel(p, x, cfg, *, ffn_kind: str, lens=None, moe_mode="sort"):
     """Returns (x, kv) where kv are the cacheables of this layer."""
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    if cfg.attn_kind == "mla":
-        o, kv = attn.mla_parallel(p["attn"], h, cfg, lens=lens)
-    else:
-        o, kv = attn.gqa_parallel(p["attn"], h, cfg, lens=lens)
-    x = _res(x + _res(o))
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if ffn_kind == "dense":
-        x = x + ffn_apply(p["mlp"], h)
-    else:
-        x = x + moe_mod.moe_ffn(p["moe"], h, cfg, mode=moe_mode)
+    with jax.named_scope(ATTN_SCOPE):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        if cfg.attn_kind == "mla":
+            o, kv = attn.mla_parallel(p["attn"], h, cfg, lens=lens)
+        else:
+            o, kv = attn.gqa_parallel(p["attn"], h, cfg, lens=lens)
+        x = _res(x + _res(o))
+    with jax.named_scope(MLP_SCOPE):
+        h = rms_norm(x, p["ln2"], cfg.norm_eps)
+        if ffn_kind == "dense":
+            x = x + ffn_apply(p["mlp"], h)
+        else:
+            x = x + moe_mod.moe_ffn(p["moe"], h, cfg, mode=moe_mode)
     return _res(x), kv
 
 
 def attn_block_decode(p, x, cache_layer, cfg, *, ffn_kind: str, moe_mode="sort"):
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    if cfg.attn_kind == "mla":
-        o, new_cache = attn.mla_decode(p["attn"], h, cache_layer, cfg)
-    else:
-        o, new_cache = attn.gqa_decode(p["attn"], h, cache_layer, cfg)
-    x = x + o
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if ffn_kind == "dense":
-        x = x + ffn_apply(p["mlp"], h)
-    else:
-        x = x + moe_mod.moe_ffn(p["moe"], h[:, None, :], cfg, mode=moe_mode)[:, 0]
+    with jax.named_scope(ATTN_SCOPE):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        if cfg.attn_kind == "mla":
+            o, new_cache = attn.mla_decode(p["attn"], h, cache_layer, cfg)
+        else:
+            o, new_cache = attn.gqa_decode(p["attn"], h, cache_layer, cfg)
+        x = x + o
+    with jax.named_scope(MLP_SCOPE):
+        h = rms_norm(x, p["ln2"], cfg.norm_eps)
+        if ffn_kind == "dense":
+            x = x + ffn_apply(p["mlp"], h)
+        else:
+            x = x + moe_mod.moe_ffn(p["moe"], h[:, None, :], cfg,
+                                    mode=moe_mode)[:, 0]
     return x, new_cache
 
 

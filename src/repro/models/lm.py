@@ -397,11 +397,13 @@ def build_lm(cfg):
                 if cfg.attn_kind == "mla":
                     def body(carry, xs, _spec=spec):
                         p_l, ckv_l, kr_l = xs
-                        h = rms_norm(carry, p_l["ln1"], cfg.norm_eps)
-                        cl = {"ckv": ckv_l, "krope": kr_l,
-                              "slot_pos": cache["slot_pos"], "pos": pos0}
-                        o, nc = attn.mla_extend(p_l["attn"], h, cl, cfg, lens_new)
-                        y = carry + o
+                        with jax.named_scope(blk.ATTN_SCOPE):
+                            h = rms_norm(carry, p_l["ln1"], cfg.norm_eps)
+                            cl = {"ckv": ckv_l, "krope": kr_l,
+                                  "slot_pos": cache["slot_pos"], "pos": pos0}
+                            o, nc = attn.mla_extend(p_l["attn"], h, cl, cfg,
+                                                    lens_new)
+                            y = carry + o
                         y = _block_ffn(p_l, y, cfg, _spec.ffn_kind)
                         return y, (nc["ckv"], nc["krope"], nc["slot_pos"])
                     x, (ckv_n, kr_n, sp_n) = layer_scan(
@@ -412,11 +414,13 @@ def build_lm(cfg):
                 else:
                     def body(carry, xs, _spec=spec):
                         p_l, k_l, v_l = xs
-                        h = rms_norm(carry, p_l["ln1"], cfg.norm_eps)
-                        cl = {"k": k_l, "v": v_l,
-                              "slot_pos": cache["slot_pos"], "pos": pos0}
-                        o, nc = attn.gqa_extend(p_l["attn"], h, cl, cfg, lens_new)
-                        y = carry + o
+                        with jax.named_scope(blk.ATTN_SCOPE):
+                            h = rms_norm(carry, p_l["ln1"], cfg.norm_eps)
+                            cl = {"k": k_l, "v": v_l,
+                                  "slot_pos": cache["slot_pos"], "pos": pos0}
+                            o, nc = attn.gqa_extend(p_l["attn"], h, cl, cfg,
+                                                    lens_new)
+                            y = carry + o
                         y = _block_ffn(p_l, y, cfg, _spec.ffn_kind)
                         return y, (nc["k"], nc["v"], nc["slot_pos"])
                     x, (k_n, v_n, sp_n) = layer_scan(
@@ -450,10 +454,11 @@ def _block_ffn(p_l, y, cfg, ffn_kind):
     from repro.models import moe as moe_mod
     from repro.models.layers import ffn_apply
 
-    h = rms_norm(y, p_l["ln2"], cfg.norm_eps)
-    if ffn_kind == "dense":
-        return y + ffn_apply(p_l["mlp"], h)
-    return y + moe_mod.moe_ffn(p_l["moe"], h, cfg)
+    with jax.named_scope(blk.MLP_SCOPE):
+        h = rms_norm(y, p_l["ln2"], cfg.norm_eps)
+        if ffn_kind == "dense":
+            return y + ffn_apply(p_l["mlp"], h)
+        return y + moe_mod.moe_ffn(p_l["moe"], h, cfg)
 
 
 def _attn_stack_cache(cfg, spec, b, m, dtype):

@@ -15,9 +15,8 @@ of executing the matmuls:
 with ``f`` the per-token forward FLOPs of the agent's model class.  The
 constants are calibrated against the real reduced engines on CPU (measured
 2026-07: llama3-7b class ≈ 32 ms TTFT at 64 uncached tokens, ≈ 35 ms per
-decoded token; qwen-4b ≈ 12 ms / 15 ms), so the "simulated engine compute"
-the `RoutingProfiler` divides routing overhead by is on the same scale the
-closed-loop oracle actually measures.
+decoded token; qwen-4b ≈ 12 ms / 15 ms), so the simulated service times
+are on the same scale the closed-loop oracle actually measures.
 
 Determinism: times are pure functions of (prompt, cache state, speed) and
 generated tokens are a hash of (dialogue, prompt length, position) — an

@@ -140,7 +140,7 @@ class SimCluster:
         self.evaluator = SimulatedSkillEvaluator(seed=seed + 1)
         self.quarantine_cooldown = quarantine_cooldown
         # attached by serving-layer profilers (repro.serving.simulator):
-        # receives add_engine_compute() per dispatch + phase() around Phase 4
+        # phase() around Phase 4, handed on to every agent's engine
         self.profiler = None
         self.agents: dict[str, AgentRuntime] = {}
         # ``profiles`` overrides the generated population: federated shards
@@ -188,6 +188,7 @@ class SimCluster:
             prices=TokenPrices(prof.price_miss, prof.price_hit, prof.price_out),
             capacity=prof.capacity, domains=prof.domains, scale=prof.scale,
             recurrent=engine.recurrent, cache_slots=engine.cache_slots)
+        engine.profiler = self.profiler
         self.agents[prof.agent_id] = AgentRuntime(
             info, prof, engine, fail_prob=fail_prob,
             straggle_prob=straggle_prob)
@@ -290,9 +291,6 @@ class SimCluster:
             obs = (pol.report(obs, quality) if pol is not None
                    else replace(obs, audit_quality=quality))
         self.telemetry.on_busy(rt.info.agent_id, total)
-        if self.profiler is not None:
-            # virtual engine seconds — the overhead-attribution denominator
-            self.profiler.add_engine_compute(total)
         heapq.heappush(self._completions, (self.now + total, self._seq, rec, obs))
         self._seq += 1
         return rec
@@ -318,8 +316,16 @@ class SimCluster:
         while self._completions and self._completions[0][0] <= self.now:
             _, _, rec, obs = heapq.heappop(self._completions)
             self.telemetry.on_complete(rec.agent_id, self.now)
-            with phase_scope(self.profiler, "phase4_feedback"):
-                router.on_complete(rec.request.request_id, obs)
+            rid = rec.request.request_id
+            with phase_scope(self.profiler, "phase4_feedback",
+                             req=rid) as span:
+                if self.profiler is not None:
+                    # what the router's affinity promised against what the
+                    # engine kept, read before on_complete retires it
+                    promised = getattr(router, "promised_hit", None)
+                    span.set(n_prompt=rec.n_prompt, n_hit=rec.n_hit,
+                             promised=promised(rid) if promised else 0)
+                router.on_complete(rid, obs)
             if not rec.failed:
                 self.records.append(rec)
             done.append(rec)

@@ -28,6 +28,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.affinity import lcp_length
 from repro.models import build_model
+from repro.utils.timing import phase_scope
 
 
 def _bucket(n: int, lo: int = 16) -> int:
@@ -107,6 +108,9 @@ class AgentEngine:
         self._decode_j = shared["decode"]
         self._extend_j = shared["extend"]
         self.evictions = 0
+        # the serving stack's RoutingProfiler, attached by the cluster:
+        # engine.serve / prefill|extend / decode spans (None: no-ops)
+        self.profiler = None
 
     def warmup(self, prefill_buckets=(32, 64, 128, 256, 512),
                extend_buckets=(16, 32, 64)) -> None:
@@ -173,10 +177,30 @@ class AgentEngine:
         measuring TTFT/total wall-clock (scaled by agent speed) and exact
         cached-token counts.  ``parents`` names sibling session keys whose
         cached state may be forked (DAG handoffs); the result is stored
-        under ``dialogue_id`` regardless."""
-        prompt = np.asarray(prompt, dtype=np.int32)
+        under ``dialogue_id`` regardless.
+
+        The whole call is the profiler's ``engine.serve`` span (``session``,
+        the routing call's ``batch``; counters ``mode``, ``n_prompt``,
+        ``n_hit``, ``n_gen``, ``evicted``); its self time is the host
+        preparation around the ``engine.prefill``/``engine.extend`` and
+        ``engine.decode`` spans."""
+        prof = self.profiler
+        evictions = self.evictions
+        with phase_scope(prof, "engine.serve", session=dialogue_id,
+                         batch=prof.batch if prof is not None else -1
+                         ) as span:
+            res, mode = self._serve(dialogue_id,
+                                    np.asarray(prompt, dtype=np.int32), now,
+                                    max_new_tokens or self.max_new, parents)
+            span.set(mode=mode, n_prompt=res.n_prompt, n_hit=res.n_hit,
+                     n_gen=res.n_gen, evicted=self.evictions - evictions)
+        return res
+
+    def _serve(self, dialogue_id: str, prompt: np.ndarray, now: float,
+               max_new: int, parents: tuple) -> tuple:
+        """`serve`'s body; returns (ServeResult, cache mode)."""
+        prof = self.profiler
         n_prompt = len(prompt)
-        max_new = max_new_tokens or self.max_new
         sess = self._pick_session(dialogue_id, prompt, parents)
 
         n_hit = 0
@@ -193,66 +217,23 @@ class AgentEngine:
                     n_hit, mode = l, "extend"
 
         t0 = time.perf_counter()
-        if mode == "identical":
-            # nothing to prefill; just decode from current state
-            cache = sess.cache
-            last_tok = jnp.asarray(prompt[-1:][None])  # placeholder
-            logits, _ = self._decode_noop(cache)
+        with phase_scope(prof, "engine.prefill" if mode == "fresh"
+                         else "engine.extend"):
+            logits, cache = self._first_logits(prompt, sess, mode, n_hit)
             jax.block_until_ready(logits)
             t_first = time.perf_counter()
-        elif mode == "extend" and n_hit < n_prompt:
-            suffix = prompt[n_hit:]
-            if self.recurrent:
-                # recurrent state cannot mask padding: exact-length extend
-                # (jit specializes per suffix length; lengths are few)
-                pad, eff = suffix, len(suffix)
-            else:
-                b = _bucket(len(suffix))
-                pad = np.zeros(b, np.int32)
-                pad[: len(suffix)] = suffix
-                eff = len(suffix)
-            cache = sess.cache
-            if not self.recurrent:
-                cache = self._truncate_attn_cache(cache, n_hit)
-            logits, cache = self._extend_j(
-                self.params, cache, jnp.asarray(pad[None]),
-                jnp.asarray([eff], jnp.int32))
-            jax.block_until_ready(logits)
-            t_first = time.perf_counter()
-        elif mode == "extend":
-            cache = sess.cache
-            if not self.recurrent:
-                cache = self._truncate_attn_cache(cache, n_hit)
-            logits, _ = self._decode_noop(cache)
-            jax.block_until_ready(logits)
-            t_first = time.perf_counter()
-        else:
-            if self.recurrent:
-                pad, eff = prompt, n_prompt
-            else:
-                b = _bucket(n_prompt)
-                pad = np.zeros(b, np.int32)
-                pad[:n_prompt] = prompt
-                eff = n_prompt
-            batch = {"tokens": jnp.asarray(pad[None]),
-                     "lens": jnp.asarray([eff], jnp.int32)}
-            if self.cfg.is_encdec:
-                batch["frames"] = jnp.zeros((1, self.cfg.src_len,
-                                             self.cfg.d_model), jnp.float32)
-            logits, cache = self._prefill_j(self.params, batch)
-            jax.block_until_ready(logits)
-            t_first = time.perf_counter()
-            n_hit = 0
 
         # greedy decode
-        out = []
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        for _ in range(max_new):
-            out.append(int(tok[0]))
-            logits, cache = self._decode_j(self.params, cache, tok)
+        with phase_scope(prof, "engine.decode") as decode:
+            out = []
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        jax.block_until_ready(logits)
-        t_end = time.perf_counter()
+            for _ in range(max_new):
+                out.append(int(tok[0]))
+                logits, cache = self._decode_j(self.params, cache, tok)
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            jax.block_until_ready(logits)
+            t_end = time.perf_counter()
+            decode.set(steps=max_new)
 
         gen = np.array(out, dtype=np.int32)
         # store the state covering prompt + generated answer (next turn will
@@ -264,7 +245,48 @@ class AgentEngine:
         ttft = (t_first - t0) / self.speed
         total = (t_end - t0) / self.speed
         return ServeResult(gen, ttft, total, n_prompt, min(n_hit, n_prompt),
-                           len(gen))
+                           len(gen)), mode
+
+    def _first_logits(self, prompt: np.ndarray, sess, mode: str,
+                      n_hit: int):
+        """The path to the first token's logits: a fresh prefill, an
+        extend past the ``n_hit`` cached tokens, or (everything cached) a
+        probe step on the truncated cache.  Returns (logits, cache)."""
+        if mode == "identical":
+            # nothing to prefill; just decode from current state
+            cache = sess.cache
+            logits, _ = self._decode_noop(cache)
+            return logits, cache
+        if mode == "extend":
+            cache = sess.cache
+            if not self.recurrent:
+                cache = self._truncate_attn_cache(cache, n_hit)
+            if n_hit == len(prompt):
+                logits, _ = self._decode_noop(cache)
+                return logits, cache
+            suffix = prompt[n_hit:]
+            if self.recurrent:
+                # recurrent state cannot mask padding: exact-length extend
+                # (jit specializes per suffix length; lengths are few)
+                pad = suffix
+            else:
+                pad = np.zeros(_bucket(len(suffix)), np.int32)
+                pad[: len(suffix)] = suffix
+            return self._extend_j(self.params, cache,
+                                  jnp.asarray(pad[None]),
+                                  jnp.asarray([len(suffix)], jnp.int32))
+        n_prompt = len(prompt)
+        if self.recurrent:
+            pad = prompt
+        else:
+            pad = np.zeros(_bucket(n_prompt), np.int32)
+            pad[:n_prompt] = prompt
+        batch = {"tokens": jnp.asarray(pad[None]),
+                 "lens": jnp.asarray([n_prompt], jnp.int32)}
+        if self.cfg.is_encdec:
+            batch["frames"] = jnp.zeros((1, self.cfg.src_len,
+                                         self.cfg.d_model), jnp.float32)
+        return self._prefill_j(self.params, batch)
 
     def _decode_noop(self, cache):
         """Cheap logits for the 'everything cached' path: one decode step on

@@ -41,7 +41,6 @@ import math
 import time
 import warnings
 from collections import defaultdict
-from contextlib import contextmanager
 
 import numpy as np
 
@@ -292,19 +291,14 @@ class FederatedSimulator:
         self.epochs = 0
         self.spill_candidates = 0
         self.spill_migrated = 0
-        self._fed_phases: dict[str, list] = {}  # name -> [wall_s, calls]
+        # federation-level phases (gossip/spill/migrate), as
+        # ``federation_<name>`` spans
+        self._fed_prof = RoutingProfiler()
 
     # ---------------- internals ----------------
-    @contextmanager
     def _phase(self, name: str):
-        """Accumulate federation-level wall-clock (gossip/spill/migrate)."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            slot = self._fed_phases.setdefault(name, [0.0, 0])
-            slot[0] += time.perf_counter() - t0
-            slot[1] += 1
+        """Time one federation-level section (gossip/spill/migrate)."""
+        return self._fed_prof.phase(f"federation_{name}")
 
     def _order(self, epoch_idx: int) -> list[int]:
         """Shard advance order this epoch (any order is bit-equivalent)."""
@@ -564,31 +558,26 @@ class FederatedSimulator:
 
     def _merge_routing(self, shard_outs: list[dict]) -> dict:
         """Sum shard profiler reports + fold in federation-level phases."""
-        engine = sum((s.get("routing") or {}).get("engine_compute_s", 0.0)
-                     for s in shard_outs)
         routing = sum((s.get("routing") or {}).get("routing_wall_s", 0.0)
                       for s in shard_outs)
         phases: dict[str, dict] = defaultdict(
             lambda: {"wall_s": 0.0, "calls": 0})
+        counters: dict[str, float] = defaultdict(int)
         for s in shard_outs:
-            for name, ph in ((s.get("routing") or {}).get("phases")
-                             or {}).items():
+            rep = s.get("routing") or {}
+            for name, ph in (rep.get("phases") or {}).items():
                 phases[name]["wall_s"] += ph.get("wall_s", 0.0)
                 phases[name]["calls"] += ph.get("calls", 0)
-        fed_wall = 0.0
-        for name, (w, c) in sorted(self._fed_phases.items()):
-            phases[f"federation_{name}"] = {"wall_s": w, "calls": c}
-            fed_wall += w
-        total = routing + fed_wall
-        for ph in phases.values():
-            ph["frac_of_engine"] = (ph["wall_s"] / engine) if engine > 0 \
-                else None
+            for name, v in (rep.get("counters") or {}).items():
+                counters[name] += v
+        fed = self._fed_prof.report()["phases"]
+        phases.update(fed)
+        fed_wall = sum(ph["wall_s"] for ph in fed.values())
         return {
-            "engine_compute_s": engine,
-            "routing_wall_s": total,
+            "routing_wall_s": routing + fed_wall,
             "shard_routing_wall_s": routing,
             "federation_wall_s": fed_wall,
-            "overhead_frac": (total / engine) if engine > 0 else None,
+            "counters": dict(sorted(counters.items())),
             "phases": dict(sorted(phases.items())),
         }
 

@@ -6,8 +6,8 @@ round loop: the whole dialogue population is pre-materialized into one
 not anything happens.  That is the right *oracle* for small bit-comparable
 runs, but it cannot express the paper's system-level regime — sustained
 many-to-many load at 100+ agents and 10k dialogues, where arrivals are an
-open-loop process and routing overhead must be attributed against engine
-compute.  This module replaces it for scale runs:
+open-loop process and routing time must be attributed per phase.  This
+module replaces it for scale runs:
 
   * **event queue** — a single heap carries dialogue ARRIVAL events (from a
     Poisson/trace `repro.serving.workload.ArrivalProcess`) and ROUTE
@@ -20,12 +20,13 @@ compute.  This module replaces it for scale runs:
     time, and at most ``max_inflight`` dialogues hold state concurrently;
     the rest wait in an admission backlog.  10k dialogues flow through a
     bounded window instead of one pre-built dict.
-  * **`RoutingProfiler`** — attributes real wall-clock per routing phase
-    (Phase-1 predict, Phase-2 solve per backend, the cross-hub spill round,
-    price-book ops, Phase-4 feedback) against *simulated engine compute*
-    (the virtual busy-seconds the engines report), so
-    `benchmarks/serving_scale.py` can report where routing overhead crosses
-    10% of engine compute as n_agents and batch size grow.
+  * **`RoutingProfiler`** — the serving stack's tracer: real wall-clock
+    per routing phase (Phase-1 predict, Phase-2 solve per backend, the
+    fused step's assembly/device/settle parts, the cross-hub spill round,
+    price-book ops, Phase-4 feedback) and per engine call, counters on
+    those spans (bid rounds, cache hits, evictions), and, while a JAX
+    profiler trace is collected, the same spans as ``iemas.*``
+    TraceAnnotations on the device trace's clock.
 
 Workflow DAGs: alongside linear `DialogueScript` turns, the simulator
 drives `repro.serving.workload.DagScript` task graphs — a step becomes
@@ -55,6 +56,7 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.mechanism import CompletionObs, Request
 from repro.serving.workload import (ArrivalProcess, DagScript, DialogueScript,
@@ -68,52 +70,90 @@ _ARRIVAL, _MIGRATE, _ROUTE = 0, 1, 2
 _EMPTY = np.zeros(0, np.int32)
 
 
-class RoutingProfiler:
-    """Wall-clock-per-phase accounting against simulated engine compute.
+class _Span:
+    """The handle ``RoutingProfiler.phase`` yields for one open span.
 
-    The router and cluster wrap their sections in ``phase(name)`` (no-ops
-    until a profiler is attached): ``route_batch`` is the umbrella around
-    one router invocation, inside which the IEMAS router nests
-    ``phase1_predict``, ``price_book``, ``phase2_solve[<backend>]`` and
-    ``phase2_spill``; ``phase4_feedback`` wraps completion feedback.  The
-    cluster reports each dispatch's virtual engine seconds through
-    ``add_engine_compute``.  ``report()`` divides the top-level routing
-    wall-clock (``route_batch`` + ``phase4_feedback`` — nested phases are
-    *inside* the umbrella and not double-counted) by the engine compute to
-    give the routing-overhead fraction the scale benchmark tables.
+    ``set(**stats)`` attaches counters known only at the end of the span
+    (bid rounds, cache hits): they are summed into the profiler's
+    ``counters`` and, while a profiler trace is being collected, written
+    on the span's ``TraceAnnotation``.
     """
 
-    #: top-level (non-nested) phases whose sum is "routing overhead"
+    __slots__ = ("_prof", "_name", "_ann")
+
+    def __init__(self, prof, name: str, ann):
+        self._prof, self._name, self._ann = prof, name, ann
+
+    def set(self, **stats) -> None:
+        counters = self._prof.counters
+        for key, v in stats.items():
+            if isinstance(v, str):
+                key, v = f"{key}.{v}", 1
+            key = f"{self._name}.{key}"
+            counters[key] = counters.get(key, 0) + v
+        if self._ann is not None:
+            self._ann.set_metadata(**stats)
+
+
+class RoutingProfiler:
+    """The serving stack's tracer: wall clock per phase, counters, and
+    spans on the profiler trace's clock.
+
+    The router, the fused routing step, the cluster and the engines wrap
+    their sections in ``phase(name, **stats)`` (no-ops until a profiler is
+    attached): ``route_batch`` is the umbrella around one router
+    invocation, inside which the IEMAS router nests ``phase1_predict``,
+    ``price_book``, ``fused_route`` (itself ``fused.assemble`` ->
+    ``fused.device`` -> ``fused.settle``), ``phase2_solve[<backend>]`` and
+    ``phase2_spill``; ``phase4_feedback`` wraps completion feedback and
+    ``engine.serve`` (``engine.prefill``/``engine.extend`` ->
+    ``engine.decode``) each engine call.  Each phase adds its wall time
+    and one call to ``phases``/``calls``.  While a JAX profiler trace is
+    being collected it also opens a ``jax.profiler.TraceAnnotation``
+    named ``iemas.<name>`` carrying ``stats`` (identifiers and sizes known
+    at entry), so host work can be placed against the device's operations;
+    otherwise the added cost is one ``is_enabled()`` check.  Counters
+    given to the yielded span's ``set`` are summed into ``counters`` as
+    ``<phase>.<stat>`` (string stats as ``<phase>.<stat>.<value>``, one
+    per span).  ``batch`` is the sequence number of the routing call in
+    progress: the event loop sets it, and the route and engine spans carry
+    it so one request's spans can be joined.
+    """
+
+    #: top-level (non-nested) phases whose sum is the routing wall time
     TOP_PHASES = ("route_batch", "phase4_feedback")
 
     def __init__(self):
         self.phases: dict[str, float] = {}
         self.calls: dict[str, int] = {}
-        self.engine_compute = 0.0   # virtual engine busy seconds
+        self.counters: dict[str, float] = {}
+        self.batch = 0              # sequence number of the routing call
         self.route_requests = 0     # requests seen across route_batch calls
         self.empty_route_calls = 0  # route_batch invocations with 0 requests
-        # fused routing step counters (core/routing_fused.py): device->host
-        # materialization boundaries, syncs that fired BEFORE decisions
-        # materialized (must stay 0 — the no-mid-sync contract), and fused
-        # jit-cache growth (the pow-2 retrace bound)
-        self.fused_host_transfers = 0
-        self.fused_mid_syncs = 0
-        self.fused_retraces = 0
 
     @contextmanager
-    def phase(self, name: str):
-        """Time one section under ``name`` (re-entrant safe, additive)."""
+    def phase(self, name: str, **stats):
+        """Time one section under ``name`` (re-entrant safe, additive);
+        yields a `_Span` whose ``set`` attaches end-of-span counters."""
+        ann = None
+        if TraceAnnotation.is_enabled():
+            ann = TraceAnnotation(f"iemas.{name}", **stats)
+            ann.__enter__()
         t0 = time.perf_counter()
         try:
-            yield
+            yield _Span(self, name, ann)
         finally:
             dt = time.perf_counter() - t0
             self.phases[name] = self.phases.get(name, 0.0) + dt
             self.calls[name] = self.calls.get(name, 0) + 1
+            if ann is not None:
+                ann.__exit__(None, None, None)
 
-    def add_engine_compute(self, seconds: float) -> None:
-        """Accumulate one dispatch's simulated engine seconds."""
-        self.engine_compute += float(seconds)
+    @property
+    def fused_retraces(self) -> int:
+        """Fused-program jit-cache growth over the run (the pow-2 retrace
+        bound), summed from the ``fused.device`` spans."""
+        return int(self.counters.get("fused.device.retraces", 0))
 
     def note_route_batch(self, n_requests: int) -> None:
         """Record one router invocation's batch size (called by the router).
@@ -127,26 +167,14 @@ class RoutingProfiler:
         if n_requests == 0:
             self.empty_route_calls += 1
 
-    def note_fused_step(self, host_transfers: int = 0, mid_syncs: int = 0,
-                        retraces: int = 0) -> None:
-        """Record one fused routing step's host-boundary accounting.
-
-        Called by `repro.core.routing_fused.FusedRoutingStep` after its
-        single materialization: ``host_transfers`` counts device->host
-        boundaries (exactly one per fused batch), ``mid_syncs`` counts any
-        sync performed before RouteDecisions materialized (zero by
-        construction — a nonzero value means the fused program was split),
-        and ``retraces`` is the fused jit-cache growth since the last step
-        (bounded by the pow-2 shape buckets).
-        """
-        self.fused_host_transfers += int(host_transfers)
-        self.fused_mid_syncs += int(mid_syncs)
-        self.fused_retraces += int(retraces)
-
     def attach(self, cluster, router) -> "RoutingProfiler":
-        """Hook this profiler into a cluster + router pair; returns self."""
+        """Hook this profiler into a cluster + router pair and every agent
+        engine the cluster holds (the real engines open their spans on
+        it; the cluster hands it to engines added later); returns self."""
         cluster.profiler = self
         router.profiler = self
+        for rt in getattr(cluster, "agents", {}).values():
+            rt.engine.profiler = self
         return self
 
     def routing_wall(self) -> float:
@@ -154,31 +182,15 @@ class RoutingProfiler:
         return sum(self.phases.get(p, 0.0) for p in self.TOP_PHASES)
 
     def report(self) -> dict:
-        """JSON-friendly attribution table (fractions of engine compute).
-
-        With zero engine compute (e.g. every dispatch failed) the fractions
-        are undefined and reported as ``None`` — strict-JSON safe, unlike
-        ``inf``.
-        """
-        ec = self.engine_compute
-        routing = self.routing_wall()
+        """JSON-friendly table: absolute routing wall time, wall time and
+        calls per phase, and the summed span counters."""
         return {
-            "engine_compute_s": ec,
-            "routing_wall_s": routing,
-            "overhead_frac": (routing / ec) if ec > 0 else None,
+            "routing_wall_s": self.routing_wall(),
             "route_requests": self.route_requests,
             "empty_route_calls": self.empty_route_calls,
-            "fused": {
-                "host_transfers": self.fused_host_transfers,
-                "mid_pipeline_syncs": self.fused_mid_syncs,
-                "retraces": self.fused_retraces,
-            },
+            "counters": dict(sorted(self.counters.items())),
             "phases": {
-                name: {
-                    "wall_s": wall,
-                    "calls": self.calls.get(name, 0),
-                    "frac_of_engine": (wall / ec) if ec > 0 else None,
-                }
+                name: {"wall_s": wall, "calls": self.calls.get(name, 0)}
                 for name, wall in sorted(self.phases.items())
             },
         }
@@ -711,7 +723,13 @@ class ShardEventLoop:
             return
         telem = cluster.telemetry.snapshot(cluster.now)
         free = cluster.free_slots()
-        with phase_scope(self.profiler, "route_batch"):
+        prof = self.profiler
+        if prof is not None:
+            prof.batch = self._rounds   # carried by this batch's spans
+        quarantined = getattr(router, "quarantined", ())
+        with phase_scope(prof, "route_batch", batch=self._rounds,
+                         n=len(batch),
+                         m=sum(aid not in quarantined for aid in free)):
             decisions = router.route_batch(batch, telem, free_slots=free)
         unmatched = []
         for dec in decisions:

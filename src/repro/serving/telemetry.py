@@ -2,8 +2,7 @@
 
 Also accumulates per-agent busy seconds (virtual engine time, reported by
 the cluster on dispatch) so the event simulator can compute fleet
-utilization and the profiler's engine-compute denominator from the same
-source the router's load features come from.
+utilization from the same source the router's load features come from.
 """
 from __future__ import annotations
 

@@ -7,15 +7,29 @@ from contextlib import nullcontext
 import jax
 
 
-def phase_scope(profiler, name: str):
-    """``profiler.phase(name)`` or a no-op context when no profiler is set.
+class _NoSpan:
+    """The span handle when no profiler is attached: ``set`` does nothing."""
+
+    def set(self, **stats) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def phase_scope(profiler, name: str, **stats):
+    """``profiler.phase(name, **stats)`` or a no-op context when no
+    profiler is set; either way the context yields a handle whose
+    ``set(**stats)`` attaches end-of-span counters.
 
     The one shared implementation of the serving-layer profiling idiom:
-    routers, the auction layer and the serving loops all call this instead
-    of re-deriving the nullcontext dispatch (the profiler itself is
-    duck-typed — see `repro.serving.simulator.RoutingProfiler`).
+    routers, the auction layer, the fused step, the engines and the
+    serving loops all call this instead of re-deriving the nullcontext
+    dispatch (see `repro.serving.simulator.RoutingProfiler`).
     """
-    return profiler.phase(name) if profiler is not None else nullcontext()
+    if profiler is None:
+        return nullcontext(_NO_SPAN)
+    return profiler.phase(name, **stats)
 
 
 class Timer:

@@ -38,6 +38,7 @@ TIER1_MODULES = {
     "test_sharding",
     "test_simulator",
     "test_system",
+    "test_tracing",
     "test_truthfulness",
 }
 

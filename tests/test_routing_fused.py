@@ -203,8 +203,9 @@ def test_fused_shape_buckets_bound_retracing():
 
 
 def test_fused_profiler_counters():
-    """Each step notes exactly one host transfer and zero mid-pipeline
-    syncs on the attached profiler."""
+    """Each step is one assemble -> device -> settle sequence of spans on
+    the attached profiler: one device span (the batch's one device->host
+    boundary) per route call."""
     from repro.serving.simulator import RoutingProfiler
 
     router = IEMASRouter(hetero_agents(), solver="dense-jax", n_hubs=1,
@@ -213,6 +214,8 @@ def test_fused_profiler_counters():
     for t in range(3):
         router.route_batch(make_batch(4, t, seed=17), dict(TELEMETRY))
     rep = prof.report()
-    assert rep["fused"]["host_transfers"] == 3
-    assert rep["fused"]["mid_pipeline_syncs"] == 0
-    assert rep["fused"]["retraces"] >= 1      # first call traced something
+    for part in ("fused.assemble", "fused.device", "fused.settle"):
+        assert rep["phases"][part]["calls"] == 3 == \
+            rep["phases"]["fused_route"]["calls"], part
+    assert rep["counters"]["fused.device.rounds"] > 0
+    assert prof.fused_retraces >= 1      # first call traced something

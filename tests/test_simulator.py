@@ -158,7 +158,7 @@ def test_truncation_reported_with_warning():
 # ------------------------------------------------------- profiler --
 def test_profiler_attribution():
     """The RoutingProfiler sees every phase the router runs and reports
-    overhead as routing wall-clock over simulated engine seconds."""
+    absolute routing wall-clock, per-phase calls and span counters."""
     cluster, router = _fresh(seed=2)
     prof = RoutingProfiler()
     spec = WorkloadSpec("coqa_like", n_dialogues=6, seed=7)
@@ -167,20 +167,23 @@ def test_profiler_attribution():
                          batch_cap=8, profiler=prof, lean=True,
                          max_new_tokens=3).run()
     rep = out["routing"]
-    assert rep["engine_compute_s"] > 0
+    phases = rep["phases"]
     assert rep["routing_wall_s"] > 0
-    assert rep["overhead_frac"] == pytest.approx(
-        rep["routing_wall_s"] / rep["engine_compute_s"])
+    assert rep["routing_wall_s"] == pytest.approx(
+        phases["route_batch"]["wall_s"] + phases["phase4_feedback"]["wall_s"])
     for phase in ("route_batch", "phase1_predict", "phase2_solve[dense]",
                   "price_book", "phase4_feedback"):
-        assert phase in rep["phases"], phase
-        assert rep["phases"][phase]["calls"] > 0
+        assert phase in phases, phase
+        assert phases[phase]["calls"] > 0
     # nested phases are inside the umbrella, never bigger than it
-    assert rep["phases"]["phase1_predict"]["wall_s"] <= \
-        rep["phases"]["route_batch"]["wall_s"]
-    # engine compute matches the telemetry busy-seconds hook
-    assert rep["engine_compute_s"] == pytest.approx(
-        cluster.telemetry.busy_seconds())
+    assert phases["phase1_predict"]["wall_s"] <= \
+        phases["route_batch"]["wall_s"]
+    # one feedback span per completion, carrying the records' own counts
+    assert phases["phase4_feedback"]["calls"] == len(cluster.records)
+    assert rep["counters"]["phase4_feedback.n_hit"] == sum(
+        r.n_hit for r in cluster.records)
+    assert rep["counters"]["phase4_feedback.n_prompt"] == sum(
+        r.n_prompt for r in cluster.records)
 
 
 def test_profiler_noop_when_absent():
@@ -412,7 +415,7 @@ def test_serve_cli_trace_file(tmp_path, capsys, monkeypatch):
 @pytest.mark.slow
 def test_10k_dialogue_scale_smoke():
     """The headline streaming regime: 10k dialogues flow through a bounded
-    window on a 64-agent analytic cluster with overhead attribution."""
+    window on a 64-agent analytic cluster with routing-time attribution."""
     cluster = SimCluster(n_agents=64, seed=0, engine_mode="analytic",
                          max_new_tokens=4)
     router = IEMASRouter(cluster.agent_infos(), solver="dense", n_hubs=4,
@@ -428,5 +431,4 @@ def test_10k_dialogue_scale_smoke():
     assert out["dialogues_completed"] == 10_000
     assert out["unfinished_dialogues"] == 0 and not out["truncated"]
     assert out["peak_inflight"] <= 256
-    assert out["routing"]["engine_compute_s"] > 0
-    assert 0 < out["routing"]["overhead_frac"] < 10
+    assert 0 < out["routing"]["routing_wall_s"] < out["wall_time_s"]
