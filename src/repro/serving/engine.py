@@ -14,7 +14,9 @@ paper's constrained-memory / frequent-eviction regime) and measures:
 This gives the paper's causal chain *physically*: routing with affinity ->
 more cached tokens -> less prefill compute -> lower TTFT and cost.
 
-Prompt lengths are bucketed (powers of two) so jit caches stay small.
+Prompt lengths are bucketed (powers of two) so jit caches stay small. The
+greedy decode loop is one device program per request (`_greedy`), its
+tokens read back once.
 """
 from __future__ import annotations
 
@@ -65,6 +67,28 @@ class ServeResult:
 _SHARED: dict = {}
 
 
+def _greedy(decode_step):
+    """The greedy decode loop as one program: ``generate(params, cache,
+    logits, n, size)`` takes the first token from ``logits``, then runs
+    ``n`` (traced) decode steps, each writing its input token into a
+    ``size``-token (static) output buffer and taking the next token from
+    the step's logits. Returns (tokens [size], cache): the cache holds every
+    generated token; the last step's logits are discarded."""
+    def generate(params, cache, logits, n, size):
+        def step(i, carry):
+            tok, cache, out = carry
+            out = out.at[i].set(tok[0])
+            logits, cache = decode_step(params, cache, tok)
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache, out
+
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        _, cache, out = jax.lax.fori_loop(
+            0, n, step, (tok, cache, jnp.zeros((size,), jnp.int32)))
+        return out, cache
+
+    return generate
+
+
 def _shared_fns(cfg: ModelConfig, max_len: int):
     key = (cfg, max_len)
     if key not in _SHARED:
@@ -75,6 +99,9 @@ def _shared_fns(cfg: ModelConfig, max_len: int):
             "prefill": jax.jit(
                 lambda p, b: model.prefill(p, {**b, "max_len": max_len})),
             "decode": jax.jit(model.decode_step),
+            # not donated: the cache may be a stored session's own
+            "generate": jax.jit(_greedy(model.decode_step),
+                                static_argnames="size"),
             "extend": jax.jit(model.extend),
         }
     return _SHARED[key]
@@ -107,6 +134,7 @@ class AgentEngine:
         self._prefill_j = shared["prefill"]
         self._decode_j = shared["decode"]
         self._extend_j = shared["extend"]
+        self._generate_j = shared["generate"]
         self.evictions = 0
         # the serving stack's RoutingProfiler, attached by the cluster:
         # engine.serve / prefill|extend / decode spans (None: no-ops)
@@ -114,12 +142,17 @@ class AgentEngine:
 
     def warmup(self, prefill_buckets=(32, 64, 128, 256, 512),
                extend_buckets=(16, 32, 64)) -> None:
-        """Pre-compile the shape buckets so TTFT excludes XLA compile time."""
+        """Pre-compile the shape buckets so TTFT excludes XLA compile time:
+        prefills, extends, the decode loop and the probe step of a prompt
+        that is cached whole."""
         for b in prefill_buckets:
             if b > self.max_len:
                 continue
-            r = self.serve("__warm__", np.arange(1, b + 1, dtype=np.int32) %
-                           (self.cfg.vocab_size - 1) + 1, max_new_tokens=1)
+            self.serve("__warm__", np.arange(1, b + 1, dtype=np.int32) %
+                       (self.cfg.vocab_size - 1) + 1, max_new_tokens=1)
+        prev = self.sessions.get("__warm__")
+        if prev is not None:
+            self.serve("__warm__", prev.prompt, max_new_tokens=1)
         for b in extend_buckets:
             ext = np.arange(1, b, dtype=np.int32) % (self.cfg.vocab_size - 1) + 1
             prev = self.sessions.get("__warm__")
@@ -183,7 +216,8 @@ class AgentEngine:
         the routing call's ``batch``; counters ``mode``, ``n_prompt``,
         ``n_hit``, ``n_gen``, ``evicted``); its self time is the host
         preparation around the ``engine.prefill``/``engine.extend`` and
-        ``engine.decode`` spans."""
+        ``engine.decode`` (counters ``steps``, the decode steps run, and
+        ``syncs``, its device-to-host reads) spans."""
         prof = self.profiler
         evictions = self.evictions
         with phase_scope(prof, "engine.serve", session=dialogue_id,
@@ -223,19 +257,16 @@ class AgentEngine:
             jax.block_until_ready(logits)
             t_first = time.perf_counter()
 
-        # greedy decode
+        # greedy decode: one program, its tokens read back once (the
+        # output buffer's size is bucketed so few sizes compile)
         with phase_scope(prof, "engine.decode") as decode:
-            out = []
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            for _ in range(max_new):
-                out.append(int(tok[0]))
-                logits, cache = self._decode_j(self.params, cache, tok)
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            jax.block_until_ready(logits)
+            out, cache = jax.block_until_ready(self._generate_j(
+                self.params, cache, logits, np.int32(max_new),
+                size=_bucket(max_new, lo=8)))
+            gen = np.asarray(out)[:max_new]
             t_end = time.perf_counter()
-            decode.set(steps=max_new)
+            decode.set(steps=max_new, syncs=1)
 
-        gen = np.array(out, dtype=np.int32)
         # store the state covering prompt + generated answer (next turn will
         # extend past it, mirroring vLLM prefix caching)
         full = np.concatenate([prompt, gen])

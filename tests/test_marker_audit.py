@@ -21,6 +21,7 @@ TIER1_MODULES = {
     "test_column_market",
     "test_dag_workload",
     "test_docs",
+    "test_engine_decode",
     "test_exploration",
     "test_federation",
     "test_hoeffding",

@@ -97,7 +97,7 @@ def test_span_names_and_stats(traced):
                                       "promised"},
             "iemas.engine.serve": {"session", "batch", "mode", "n_prompt",
                                    "n_hit", "n_gen", "evicted"},
-            "iemas.engine.decode": {"steps"}}
+            "iemas.engine.decode": {"steps", "syncs"}}
     for name, keys in want.items():
         for s in _named(spans, name):
             assert set(s[3]) == keys, (name, s[3])
@@ -171,9 +171,10 @@ def test_counters_equal_ground_truth(traced):
     rounds = [s[3]["rounds"] for s in _named(spans, "iemas.fused.device")]
     assert rounds == [res.solver_stats["rounds"]
                       for res in traced["results"]]
-    decode_steps = sum(s[3]["steps"] for s in
-                       _named(spans, "iemas.engine.decode"))
-    assert decode_steps == sum(r.n_gen for r in records)
+    decode = [s[3] for s in _named(spans, "iemas.engine.decode")]
+    assert sum(s["steps"] for s in decode) == sum(r.n_gen for r in records)
+    # the decode loop runs on the device: one read-back per request
+    assert sum(s["syncs"] for s in decode) == len(decode) == len(records)
 
 
 def _analytic_run(profiler):
