@@ -48,4 +48,5 @@ def test_declared():
     m, = [m for m in loader.benchmark()["per_layer"] if m["name"] == NAME]
     assert m == {"name": NAME, "unit": "syncs/tok", "better": "lower",
                  "source": "program_counter", "layer": "engine",
-                 "moves": "req_per_s", "workloads": ["qwen3-8b-x2.coqa"]}
+                 "moves": "req_per_s",
+                 "workloads": ["qwen3-8b-x2.coqa", "qwen3-8b-x4.coqa"]}

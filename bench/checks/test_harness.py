@@ -46,17 +46,17 @@ def test_refuses_in_a_bare_checkout(tmp_path):
     assert proc.returncode != 0 and _no_result(proc)
 
 
-def _tiny(kind):
-    if kind == "served":
-        return (loader.load_json(FIX / "tiny-x2.json"),
-                loader.load_json(FIX / "tiny-coqa.json"))
-    return (loader.load_json(FIX / "tiny-fleet.json"),
-            loader.load_json(FIX / "tiny-open.json"))
+#: tiny cells: configuration, traffic and the devices the cell asks for
+TINY = {"served": ("tiny-x2.json", "tiny-coqa.json", 1),
+        "served4": ("tiny-x4.json", "tiny-coqa32.json", 4),
+        "fleet": ("tiny-fleet.json", "tiny-open.json", 1)}
 
 
 def _run(kind, hook=None, seed=2**31 + 77, trace=False, controls=None):
-    config, traffic = _tiny(kind)
-    cell = {"name": "tiny", "chips": 1}
+    config_file, traffic_file, chips = TINY[kind]
+    config = loader.load_json(FIX / config_file)
+    traffic = loader.load_json(FIX / traffic_file)
+    cell = {"name": "tiny", "chips": chips}
     return harness.run_cell(loader.benchmark(), cell, config, traffic, seed,
                             2.0, trace, time.perf_counter(),
                             require_chip=False, hook=hook, controls=controls)
@@ -170,3 +170,56 @@ def test_broken_path_is_not_correct(kind, fault):
     assert res["correct"] is False
     failed = [n for n, c in res["checks"].items() if c["value"] > c["limit"]]
     assert failed, res["checks"]
+
+
+@pytest.mark.parametrize("kind", ["served4", "served"])
+def test_agents_on_the_cells_own_devices(kind):
+    """Agent i's weights and session caches sit on device i mod the cell's
+    devices: four agents on four devices each on its own, and two agents
+    of a one-device cell both on device 0, though the CPU shows four and
+    the program's cluster first places its own weights round-robin."""
+    import jax
+
+    assert len(jax.devices()) >= 4, "bench/checks/conftest.py sets four"
+    chips = TINY[kind][2]
+    res = _run(kind)
+    assert res["correct"], res["checks"]
+    placement = res["window"]["placement"]
+    assert len(placement) == (4 if kind == "served4" else 2)
+    for i, where in enumerate(placement.values()):
+        assert where["weights"] == [i % chips], placement
+        assert where["sessions"] in ([], [i % chips]), placement
+    # 32 dialogues in flight outgrow one agent's 12 slots and spread
+    held = sum(bool(w["sessions"]) for w in placement.values())
+    assert held >= (2 if kind == "served4" else 1), placement
+    # what each agent served in the window adds up to the window's count
+    by_agent = res["window"]["by_agent"]
+    assert list(by_agent) == list(placement)
+    assert sum(a["served"] for a in by_agent.values()) \
+        == res["window"]["served"]
+    for a in by_agent.values():
+        assert (a["kv_hit_share"] is None) == (a["served"] == 0)
+
+
+def _compile_loop_in_window(system):
+    """The first serve inside the window asks for more tokens than the
+    warm pass did, so the greedy-loop program compiles its next output
+    size there."""
+    forced = []
+    for rt in system.cluster.agents.values():
+        serve = rt.engine.serve
+
+        def more_tokens(*a, _serve=serve, **kw):
+            if system.rec.active and not forced:
+                forced.append(True)
+                kw["max_new_tokens"] = 9      # output bucket 16, not 8
+            return _serve(*a, **kw)
+
+        rt.engine.serve = more_tokens
+
+
+def test_loop_compile_in_window_is_counted():
+    res = _run("served", hook=_compile_loop_in_window)
+    assert res["window"]["engine_compiles"] >= 1
+    assert any(e["where"] == "engine" and e["in_window"]
+               for e in res["window"]["compile_events"])

@@ -93,4 +93,31 @@ def test_every_new_metric_is_declared():
     spec = loader.benchmark()
     declared = {m["name"]: m for m in spec["per_layer"]}
     for name in EXPECTED:
-        assert declared[name]["workloads"] == ["qwen3-8b-x2.coqa"]
+        assert declared[name]["workloads"] == ["qwen3-8b-x2.coqa",
+                                               "qwen3-8b-x4.coqa"]
+
+
+#: the engine idle readers on two_chip_trace.pbtxt, by hand (ns -> ms): idle
+#: is time in which no chip runs, so the chip that is not serving (and the
+#: one that serves nothing in the window) does not add idle time
+ON_FOUR_CHIPS = {
+    # decode 300..560 holds TPU:0's 250..550, decode 700..980 TPU:1's
+    # 650..950: idle 10 + 30 ns over 4 steps
+    "engine.decode_idle_ms_per_tok": 10e-6,
+    # serves outside their decode: 200..300 (busy from 250), 560..600,
+    # 600..700 (busy from 650), 980..1000: idle 50 + 40 + 50 + 20 ns over
+    # 2 serves
+    "engine.prep_idle_ms_per_req": 80e-6,
+}
+
+
+def test_idle_on_several_chips(tmp_path, monkeypatch):
+    ctx = _ctx("two_chip_trace.pbtxt", tmp_path, monkeypatch)
+    assert program.idle_ns(ctx, [(200, 600)]) == 100
+    assert program.idle_ns(ctx, [(0, 1000)]) == 1000 - 100 - 300 - 300
+
+
+@pytest.mark.parametrize("name", sorted(ON_FOUR_CHIPS))
+def test_engine_idle_reader_on_several_chips(name, tmp_path, monkeypatch):
+    ctx = _ctx("two_chip_trace.pbtxt", tmp_path, monkeypatch)
+    assert loader.metric(name).read(ctx) == pytest.approx(ON_FOUR_CHIPS[name])

@@ -5,7 +5,6 @@ import numpy as np
 import pytest
 
 import loader
-from system import dense_lm_config
 
 
 @pytest.mark.parametrize("shape", [(16, 2), (40, 9), (64, 24)])
@@ -66,7 +65,7 @@ def test_dense_reference_matches_program_forward():
     from repro.models import build_model
 
     ref = loader.reference("dense_lm")
-    model = build_model(dense_lm_config(TINY))
+    model = build_model(loader.adapter("dense_lm").program_config(TINY))
     params = ref.make_params(TINY, 7, 0)
     rng = np.random.default_rng(0)
     prompt = rng.integers(1, 512, 21).astype(np.int32)
@@ -98,7 +97,7 @@ def test_engine_control_separates():
 
     ref = loader.reference("dense_lm")
     cfg = dict(TINY, torch_dtype="bfloat16", vocab_size=4096)
-    model = build_model(dense_lm_config(cfg))
+    model = build_model(loader.adapter("dense_lm").program_config(cfg))
     pairs = []
     for s in range(6):
         params = ref.make_params(cfg, 11, 0)

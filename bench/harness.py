@@ -90,6 +90,16 @@ def _sample(rng, items: list, k: int, key) -> list:
     return [items[top]] + [items[rest[i]] for i in sorted(pick)]
 
 
+def _agent_share(served: list, agent: str) -> dict:
+    """What one agent served in the window: requests, and the share of
+    their prompt tokens it found cached (%)."""
+    mine = [r for r in served if r["agent"] == agent]
+    prompt = sum(r["n_prompt"] for r in mine)
+    return {"served": len(mine),
+            "kv_hit_share": (100.0 * sum(r["n_hit"] for r in mine) / prompt
+                             if prompt else None)}
+
+
 def check_routing(rec, check: dict, rng, controls=()) -> dict:
     """Worst numbers over the window's routed markets (or a seeded sample):
     Phase 2's allocation and payments against the exact solver, and Phase
@@ -172,13 +182,15 @@ def run_cell(spec: dict, cell: dict, config: dict, traffic: dict, seed: int,
     import jax
 
     use_compile_cache()
-    devices = chips(cell["chips"]) if require_chip else jax.devices()[:1]
+    # the cell's own chips; off the chip (the checks) the CPU's devices
+    devices = (chips(cell["chips"]) if require_chip
+               else jax.devices()[: cell["chips"]])
     sys.path.insert(0, str(loader.ROOT / "src"))
     import system as system_mod
 
     sysm_phases = {"start": time.perf_counter() - t_start}
     t = time.perf_counter()
-    sysm = system_mod.System(config, traffic, seed, hook=hook)
+    sysm = system_mod.System(config, traffic, seed, devices, hook=hook)
     sysm_phases["build"] = time.perf_counter() - t
     t = time.perf_counter()
     sysm.warm_engines()
@@ -220,6 +232,8 @@ def run_cell(spec: dict, cell: dict, config: dict, traffic: dict, seed: int,
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in devices)
     agent_index = {aid: i for i, aid in enumerate(sysm.cluster.agents)}
+    placement = sysm.placement()
+    by_agent = {aid: _agent_share(rec.served, aid) for aid in agent_index}
     model = config if sysm.model_cfg is not None else None
     sysm.free()
     del sysm
@@ -229,7 +243,8 @@ def run_cell(spec: dict, cell: dict, config: dict, traffic: dict, seed: int,
     metrics: dict = {}
     if trace:
         reduce = loader.module(loader.BENCH / "trace" / "reduce.py")
-        tr = reduce.Trace.load(reduce.find_trace(str(TRACE_DIR)))
+        tr = reduce.Trace.load(reduce.find_trace(str(TRACE_DIR)),
+                               chips=len(devices))
         # the per-layer metrics read the traced part of the window only
         t_end = traced["t1"]
         ctx = SimpleNamespace(
@@ -296,7 +311,9 @@ def run_cell(spec: dict, cell: dict, config: dict, traffic: dict, seed: int,
         "route_calls": len(rec.route_calls),
         "fused_compiles": fused_compiles, "engine_compiles": engine_compiles,
         "compile_events": rec.compiles[-12:], "setup_phases": sysm_phases,
-        "checked": sampled}
+        "placement": placement, "by_agent": by_agent, "checked": sampled}
+    if tr is not None:
+        result["window"]["busy_by_device"] = tr.busy_by_device()
     if controls:
         result["control_checks"] = {}
         for c, got in control_numbers.items():

@@ -10,6 +10,7 @@ traffic mix, a generator, a metric or a reference by adding files:
     bench/traffic/arrivals.py           the arrival schedules
     bench/metrics/<metric>.py           a per-layer metric's reader
     bench/reference/<name>.py           a plain reference
+    bench/adapters/<family>.py          the program's config for a model family
 """
 from __future__ import annotations
 
@@ -74,6 +75,10 @@ def metric(name: str):
 
 def reference(name: str):
     return module(BENCH / "reference" / f"{name}.py")
+
+
+def adapter(family: str):
+    return module(BENCH / "adapters" / f"{family}.py")
 
 
 def peaks(device_kind: str) -> dict:

@@ -1,5 +1,5 @@
 """Device idle time inside the engines' greedy decode loops, per decode
-step (ms/tok): the time no operation ran on the device inside the
+step (ms/tok): the time no chip ran an operation inside the
 program's ``iemas.engine.decode`` spans, over the sum of their ``steps``
 counters. The host-synced token loop's loss per generated token."""
 import loader

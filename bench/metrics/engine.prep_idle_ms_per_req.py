@@ -1,5 +1,5 @@
 """Device idle time while an engine prepares a request, per served request
-(ms/req): the time no operation ran on the device inside the program's
+(ms/req): the time no chip ran an operation inside the program's
 ``iemas.engine.serve`` spans but outside their prefill/extend/decode
 children (session pick, LCP, padding, uploads, the stored prompt, LRU
 eviction), over the serves."""

@@ -1,15 +1,22 @@
 """Plain reference for the dense GQA decoder with per-head qk-norm (Qwen3).
 
-Two things live here, keyed by the configuration file's own (Hugging
-Face) keys:
+A configuration's ``engine.family`` names this file, and the harness takes
+everything model-specific from it, keyed by the configuration file's own
+(Hugging Face) keys:
 
 * ``make_params``: the weights, made by the benchmark from ``--seed`` in one
-  jitted call, in the layout the program's engine takes (a stacked layer
-  axis), in the configuration's dtype;
+  jitted call on the agent's device, in the layout the program's engine
+  takes (a stacked layer axis), in the configuration's dtype;
+* ``call_counts``: the operations and bytes one forward call needs, from
+  its shapes (`counts.served_request` sums them over a served request);
 * ``served_gaps``: the reference forward pass in float32 under
   ``jax.default_matmul_precision("highest")``, layer by layer and in blocks
   of rows, at full width, and the gap by which each served token's logit
   lies below the reference's best at its position.
+
+A second family is a second file beside this one with the same three
+functions, and a file `adapters/<family>.py` with the program's
+``program_config``.
 
 The forward pass follows the published Qwen3 block: RMSNorm, q/k/v
 projections, RMSNorm over each head of q and k, rotary embedding (half
@@ -88,11 +95,39 @@ def _param_maker(dims: tuple, dtype: str):
     return jax.jit(make)
 
 
-def make_params(config: dict, seed: int, index: int):
-    """Agent ``index``'s weights for ``seed``, on the default device, in the
-    configuration's dtype (one jitted call)."""
-    return _param_maker(_dims(config), str(config["torch_dtype"]))(
-        seed_key(seed, index))
+def make_params(config: dict, seed: int, index: int, device=None):
+    """Agent ``index``'s weights for ``seed``, made on ``device`` (the
+    default device where None) in the configuration's dtype, in one jitted
+    call."""
+    key = seed_key(seed, index)
+    if device is not None:
+        key = jax.device_put(key, device)
+    return _param_maker(_dims(config), str(config["torch_dtype"]))(key)
+
+
+def call_counts(config: dict, new_tokens: int, context: int,
+                head_rows: int = 1) -> tuple:
+    """One forward call of the dense decoder: ``new_tokens`` tokens computed
+    after ``context`` cached ones, logits for ``head_rows`` positions.
+    Returns (operations, bytes moved at the least).
+
+    Operations: 2 per multiply-add of every projection for each new token,
+    the attention scores and mixing over the causal context, and the LM head.
+    Bytes: every layer's weights and the LM head read once per call (bf16,
+    the configuration's dtype), the new tokens' embedding rows, the cached
+    keys and values read, and the new ones written."""
+    L, D, H, K, hd, F, V = _dims(config)
+    w = 2  # bytes per weight and cache element (bfloat16)
+    t, c = int(new_tokens), int(context)
+    proj = D * (H + 2 * K) * hd + H * hd * D + 3 * D * F
+    # causal: token k of the block sees c + k + 1 positions
+    seen = t * c + t * (t + 1) // 2
+    ops = L * (2 * proj * t + 4 * H * hd * seen) + 2 * D * V * head_rows
+    layer_w = proj + 2 * D + 2 * hd
+    kv_read = L * 2 * K * hd * c * w
+    kv_write = L * 2 * K * hd * t * w
+    nbytes = (L * layer_w + D * V + D) * w + t * D * w + kv_read + kv_write
+    return ops, nbytes
 
 
 # ---------------- the reference forward pass ----------------

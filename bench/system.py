@@ -1,12 +1,20 @@
 """The system under test, built from one configuration and one traffic mix.
 
-This is the only module of the benchmark that touches the program
+This is the only module of the benchmark that drives the program
 (``repro``). It builds the served path that the window drives:
 
     EventSimulator -> SimCluster -> make_router(RouterConfig(...))
                    -> AgentEngine (or AnalyticEngine) per agent
 
-and wraps four of the program's calls from outside, without editing it:
+Everything model-specific comes from the files that the configuration's
+``engine.family`` names: the program's model config from
+`adapters/<family>.py`, and the weights (and, for the harness, the counts
+and the check) from `reference/<family>.py`. Agent ``i``'s weights are
+made on ``devices[i % len(devices)]``, the cell's own chips; its engine's
+programs run where their inputs are committed, so its session caches
+follow the weights there.
+
+It wraps four of the program's calls from outside, without editing it:
 ``router.route_batch``, ``router.on_complete``, ``cluster.execute`` and each
 engine's ``serve``. The wrappers record host spans (wall clock, and a
 ``jax.profiler.TraceAnnotation`` of the same name in traced runs), the
@@ -55,13 +63,15 @@ class Recorder:
 class System:
     """One cell's cluster, router and event loop, with the bench's wrappers."""
 
-    def __init__(self, config: dict, traffic: dict, seed: int, hook=None):
+    def __init__(self, config: dict, traffic: dict, seed: int, devices: list,
+                 hook=None):
         from repro.configs.iemas_cluster import RouterConfig, agent_profiles
         from repro.serving import SimCluster, make_router
         from repro.serving.simulator import EventSimulator, RoutingProfiler
         from repro.serving.workload import DialogueScript
 
         self.config, self.traffic, self.seed = config, traffic, seed
+        self.devices = list(devices)
         self.rec = Recorder()
         engine = config["engine"]
         n_agents = int(config["agents"])
@@ -69,7 +79,8 @@ class System:
         profiles = agent_profiles(n_agents)
         if engine["kind"] == "model":
             self.family = loader.reference(engine["family"])
-            self.model_cfg = dense_lm_config(config)
+            self.model_cfg = loader.adapter(engine["family"]).program_config(
+                config)
             self.vocab = int(config["vocab_size"])
             self.cluster = SimCluster(
                 n_agents=n_agents, seed=0, max_new_tokens=max_new,
@@ -111,7 +122,8 @@ class System:
     # ---------------- weights (made by the benchmark, from the seed) -----
     def _install_weights(self) -> None:
         """Replace each engine's own initial weights by the benchmark's,
-        made on the device from ``--seed`` in one jitted call per agent."""
+        made from ``--seed`` in one jitted call per agent, on agent
+        ``index``'s device ``devices[index % len(devices)]``."""
         import jax
 
         for index, rt in enumerate(self.cluster.agents.values()):
@@ -119,7 +131,9 @@ class System:
             want = jax.tree.map(lambda a: (a.shape, a.dtype), eng.params)
             eng.params = None           # free the engine's own weights first
             gc.collect()
-            params = self.family.make_params(self.config, self.seed, index)
+            device = self.devices[index % len(self.devices)]
+            params = self.family.make_params(self.config, self.seed, index,
+                                             device=device)
             got = jax.tree.map(lambda a: (a.shape, a.dtype), params)
             if got != want:
                 raise ValueError("benchmark weights do not match the "
@@ -149,7 +163,24 @@ class System:
             return 0
         eng = next(iter(self.cluster.agents.values())).engine
         return sum(f._cache_size() for f in
-                   (eng._prefill_j, eng._extend_j, eng._decode_j))
+                   (eng._prefill_j, eng._extend_j, eng._decode_j,
+                    eng._generate_j))
+
+    def placement(self) -> dict:
+        """The devices (ids) that hold each agent's weights and session
+        caches: {agent: {"weights": [...], "sessions": [...]}}."""
+        import jax
+
+        def ids(tree) -> list:
+            return sorted({d.id for a in jax.tree.leaves(tree)
+                           for d in a.devices()})
+
+        if self.model_cfg is None:
+            return {}
+        return {aid: {"weights": ids(rt.engine.params),
+                      "sessions": ids([s.cache for s in
+                                       rt.engine.sessions.values()])}
+                for aid, rt in self.cluster.agents.items()}
 
     # ---------------- wrappers ----------------
     def _wrap(self) -> None:
@@ -327,31 +358,6 @@ class System:
             eng.sessions.clear()
         self.sim = self.router = self.cluster = None
         gc.collect()
-
-
-def dense_lm_config(config: dict):
-    """The program's `ModelConfig` for a dense GQA + qk-norm configuration
-    file's (Hugging Face) keys."""
-    import dataclasses
-
-    from repro.configs import get_config
-
-    if config.get("attention_bias") or config.get("tie_word_embeddings"):
-        raise ValueError("the dense reference has no attention bias and an "
-                         "untied LM head")
-    return dataclasses.replace(
-        get_config("qwen3-8b"),
-        n_layers=int(config["num_hidden_layers"]),
-        d_model=int(config["hidden_size"]),
-        n_heads=int(config["num_attention_heads"]),
-        n_kv_heads=int(config["num_key_value_heads"]),
-        head_dim=int(config["head_dim"]),
-        d_ff=int(config["intermediate_size"]),
-        vocab_size=int(config["vocab_size"]),
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        qk_norm=True, qkv_bias=False, tie_embeddings=False,
-        dtype=str(config["torch_dtype"]))
 
 
 def _session(request):

@@ -10,9 +10,11 @@ harness's trace directory), loaded once per file, and clips them to
 ``bench.window``. A program that writes no such spans gives ``None``, so a
 metric built on them is left out of the result line.
 
-Device idle time inside spans uses the busy union of `reduce.Trace`
-(``ctx.trace.busy``), so host spans and device operations are compared on
-one clock.
+Device idle time inside spans is the time in which no chip ran an
+operation (`reduce.Trace.busy_union`), so host spans and device operations
+are compared on one clock. The serving loop dispatches one request at a
+time and waits for it, so inside an ``iemas.engine.*`` span only the
+serving agent's chip works: the spans need not name the chip.
 """
 from __future__ import annotations
 
@@ -109,13 +111,9 @@ def self_ns(found: list, name: str) -> float:
 
 
 def idle_ns(ctx, intervals: list) -> float:
-    """Device idle time inside ``intervals`` [(start, end)] (merged),
-    averaged over the devices that ran any operation."""
+    """Time inside ``intervals`` [(start, end)] (merged) in which no chip
+    ran an operation."""
     reduce = _reduce()
     union = reduce.merge(intervals)
     total = float((union[:, 1] - union[:, 0]).sum()) if len(union) else 0.0
-    busy = ctx.trace.busy
-    if not busy:
-        return total
-    return total - sum(reduce.overlap(u, intervals) for u in busy.values()) \
-        / len(busy)
+    return total - reduce.overlap(ctx.trace.busy_union(), intervals)

@@ -11,8 +11,12 @@ benchmark's own spans, written as ``jax.profiler.TraceAnnotation``s named
 computed on the trace's own clock.
 
 * device busy time: the union of the operations' intervals inside the
-  window, averaged over the devices that ran any;
-* device time inside host spans: the busy union clipped to spans of a name;
+  window, averaged over the chips the cell holds (each device's own union;
+  a chip that ran nothing in the window counts as busy 0, whether or not
+  the trace has a plane for it);
+* device time inside host spans: each device's busy union clipped to spans
+  of a name, summed over the devices (device-seconds: work that one chip
+  does while the others wait counts once, whichever chip does it);
 * kernel events: operations whose instruction name is a kernel's;
 * the breakdown: the programs and operations that took most device time,
   and the longest idle gaps labelled by the host span they fall in.
@@ -84,9 +88,10 @@ def overlap(union: np.ndarray, spans) -> float:
 
 class Trace:
     """One traced window (a ``jax.profiler.ProfileData``): device operations
-    and bench host spans, in ns."""
+    and bench host spans, in ns. ``chips`` is how many chips the cell holds;
+    where the trace shows more devices with operations, those count."""
 
-    def __init__(self, pd):
+    def __init__(self, pd, chips: int = 0):
         self.ops: dict = {}           # device plane -> [(s, e, op name)]
         self.modules: dict = {}       # device plane -> [(s, e, program)]
         self.spans = defaultdict(list)  # bench span name -> [(s, e)]
@@ -111,36 +116,49 @@ class Trace:
             raise ValueError("the trace holds no bench.window span")
         self.t0 = min(s for s, _ in self.spans[WINDOW])
         self.t1 = max(e for _, e in self.spans[WINDOW])
+        # each device's busy union inside the window (empty where it ran
+        # nothing there)
         self.busy = {dev: merge([(max(s, self.t0), min(e, self.t1))
                                  for s, e, _ in rows
                                  if e > self.t0 and s < self.t1])
                      for dev, rows in self.ops.items()}
+        self.chips = max(int(chips), len(self.busy))
 
     @classmethod
-    def load(cls, path: str) -> "Trace":
+    def load(cls, path: str, chips: int = 0) -> "Trace":
         from jax.profiler import ProfileData
 
-        return cls(ProfileData.from_file(path))
+        return cls(ProfileData.from_file(path), chips)
 
     # ---------------- reductions ----------------
     @property
     def window_s(self) -> float:
         return (self.t1 - self.t0) * 1e-9
 
-    def busy_s(self) -> float:
-        """Device busy seconds in the window, averaged over devices used."""
-        if not self.busy:
-            return 0.0
-        return float(np.mean([(u[:, 1] - u[:, 0]).sum() for u in
-                              self.busy.values()])) * 1e-9
+    def busy_by_device(self) -> dict:
+        """Busy seconds in the window of each device the trace shows, by
+        plane name."""
+        return {dev: float((u[:, 1] - u[:, 0]).sum()) * 1e-9
+                for dev, u in self.busy.items()}
 
-    def busy_in(self, span: str) -> float:
-        """Device busy seconds inside the host spans named ``span``."""
-        spans = self.spans.get(span, [])
-        if not self.busy or not spans:
+    def busy_s(self) -> float:
+        """Device busy seconds in the window, averaged over the cell's
+        chips."""
+        if not self.chips:
             return 0.0
-        return float(np.mean([overlap(u, spans) for u in
-                              self.busy.values()])) * 1e-9
+        return sum(self.busy_by_device().values()) / self.chips
+
+    def busy_union(self) -> np.ndarray:
+        """The intervals in which any chip ran an operation (merged)."""
+        return merge([tuple(iv) for u in self.busy.values() for iv in u])
+
+    def busy_in_sum(self, span: str) -> float:
+        """Device-seconds busy inside the host spans named ``span``, summed
+        over the devices."""
+        spans = self.spans.get(span, [])
+        if not spans:
+            return 0.0
+        return sum(overlap(u, spans) for u in self.busy.values()) * 1e-9
 
     def kernel_events(self, kernel: str) -> list:
         """(start, end) of every operation of the kernel named ``kernel``
