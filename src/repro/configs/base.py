@@ -11,6 +11,23 @@ from dataclasses import dataclass, field
 
 
 @dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling (arXiv:2309.00071), under the published
+    ``rope_scaling`` keys of a Hugging Face config: the rope frequencies
+    are stretched by ``factor`` outside the band of rotations set by
+    ``beta_fast``/``beta_slow`` at ``original_max_position_embeddings``,
+    and the attention logits are scaled by YaRN's mscale terms
+    (``models.layers.yarn_inv_freq``, ``models.attention.mla_softmax_scale``)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str  # dense | moe | ssm | hybrid | audio | vlm
@@ -28,6 +45,7 @@ class ModelConfig:
     qk_norm: bool = False
     sliding_window: int = 0  # 0 = full attention
     rope_theta: float = 10000.0
+    rope_scaling: RopeScaling | None = None  # None: plain rope
 
     # MLA (deepseek-v2)
     kv_lora_rank: int = 0
@@ -40,9 +58,9 @@ class ModelConfig:
     n_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0  # expert hidden size when different from d_ff
-    first_dense_layers: int = 0
-    dense_d_ff: int = 0  # FFN size of the leading dense layers (0 -> d_ff)
-    capacity_factor: float = 1.25  # MoE dispatch capacity factor
+    first_dense_layers: int = 0  # leading dense layers, of width d_ff
+    norm_topk_prob: bool = True  # renormalise the top-k gate weights
+    routed_scaling_factor: float = 1.0  # times the routed experts' sum
 
     # SSM / hybrid
     ssm_kind: str = ""  # rwkv6 | mamba2

@@ -36,7 +36,6 @@ DEFAULT_RULES: dict = {
     "ff": "model",
     "vocab": "model",
     "expert": "model",
-    "expert_capacity": None,
     "kv_lora": None,
     "state": None,
     "inner": "model",   # SSM inner projections (rwkv/mamba d_inner)

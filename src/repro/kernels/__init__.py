@@ -4,8 +4,8 @@ Two-file pattern per op: ``<name>.py`` holds the `pl.pallas_call` kernel,
 which callers import (compiled on TPU, interpret mode elsewhere: see
 `resolve_interpret`), and ``ref.py`` the simplest-possible pure-jnp oracle
 it is validated against.  Current kernels: LCP affinity (router Phase 1), the dense
-auction's forward-bidding round (router Phase 2), flash/decode attention,
-WKV6 and SSD recurrences (serving engines).
+auction's forward-bidding round (router Phase 2), the MoE's grouped expert
+FFN (serving engines), flash/decode attention, WKV6 and SSD recurrences.
 """
 
 

@@ -156,3 +156,18 @@ def ssd_ref(x, bmat, cmat, dt, a_log, d_skip, s0):
           dt.swapaxes(0, 1))
     sT, y = jax.lax.scan(step, jnp.asarray(s0, jnp.float32), xs)
     return y.swapaxes(0, 1), sT
+
+
+# ---------------- grouped expert FFN ----------------
+
+def moe_gmm_ref(x, sizes, wg, wu, wd):
+    """The routed experts' SwiGLU over rows sorted by expert, in pure jnp
+    (the kernel's oracle and its backward pass). x: [R, D]; sizes: [E]
+    rows per expert; wg, wu: [E, D, F]; wd: [E, F, D]. Rows past
+    ``sum(sizes)`` read as zeros."""
+    g = jax.lax.ragged_dot(x, wg, sizes, preferred_element_type=jnp.float32)
+    u = jax.lax.ragged_dot(x, wu, sizes, preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    return jax.lax.ragged_dot(h, wd, sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(x.dtype)

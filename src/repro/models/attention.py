@@ -33,7 +33,7 @@ CHUNK_Q = 1024
 
 
 def attend_parallel(q, k, v, *, causal: bool = True, window: int = 0,
-                    q_offset=0, kv_valid_len=None):
+                    q_offset=0, kv_valid_len=None, scale=None):
     """Full parallel attention; GQA handled by broadcasting K/V to H heads so
     the [B, H, Sq, Sk] score tensor shards over the FULL head count (8 KV
     heads cannot divide a 16-way model axis; 64 query heads can).
@@ -45,12 +45,14 @@ def attend_parallel(q, k, v, *, causal: bool = True, window: int = 0,
     q: [B, Sq, H, hd]; k, v: [B, Sk, Hkv, hd].
     q_offset: absolute position of q[0] minus kv[0] (chunked prefill support).
     kv_valid_len: [B] valid key length (right-padded prompts).
+    scale: the softmax scale (None: 1/sqrt(hd)).
     """
     from repro.models.scan_config import layer_scan
 
     b, sq, h, hd = q.shape
     sk, n_kv = k.shape[1], k.shape[2]
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
     k_pos = jnp.arange(sk)
     kmask = None
     if kv_valid_len is not None:
@@ -211,27 +213,33 @@ def prefill_cache_layout(k, v, lens, max_len: int, *, window: int = 0):
 
 
 def attend_mixed(q, k_new, v_new, k_cache, v_cache, slot_pos, pos0, lens_new,
-                 *, window: int = 0):
+                 *, window: int = 0, scale=None):
     """Chunked-prefill attention: new tokens attend to (cache + new block).
 
     q, k_new, v_new: [B, Sn, H(kv), hd]; caches: [B, M, Hkv, hd];
-    pos0: [B] absolute position of the first new token; lens_new: [B].
+    pos0: [B] absolute position of the first new token; lens_new: [B];
+    scale: the softmax scale (None: 1/sqrt(hd)).
     Used by the serving engine for multi-turn KV reuse (the paper's o_ij).
     """
     b, sn, h, hd = q.shape
+
+    def scaled(scores):
+        scores = scores.astype(jnp.float32)
+        return scores / jnp.sqrt(hd) if scale is None else scores * scale
+
     n_kv = k_new.shape[2]
     qg = _group(q, n_kv)
     q_pos = pos0[:, None] + jnp.arange(sn)[None, :]  # [B,Sn]
 
     # scores vs cache slots
-    sc = jnp.einsum("bskgd,bmkd->bkgsm", qg, k_cache).astype(jnp.float32) / jnp.sqrt(hd)
+    sc = scaled(jnp.einsum("bskgd,bmkd->bkgsm", qg, k_cache))
     valid_c = (slot_pos >= 0)[:, None, :] & (slot_pos[:, None, :] <= q_pos[..., None])
     if window:
         valid_c &= (q_pos[..., None] - slot_pos[:, None, :]) < window
     sc = jnp.where(valid_c[:, None, None], sc, NEG_INF)  # [B,Sn,M]->[B,1,1,Sn,M]
 
     # scores vs new block (causal within block, length-masked)
-    sb = jnp.einsum("bskgd,btkd->bkgst", qg, k_new).astype(jnp.float32) / jnp.sqrt(hd)
+    sb = scaled(jnp.einsum("bskgd,btkd->bkgst", qg, k_new))
     t_idx = jnp.arange(sn)
     mask_b = (t_idx[None, :, None] >= t_idx[None, None, :])  # s >= t (causal)
     mask_b = mask_b & (t_idx[None, None, :] < lens_new[:, None, None])
@@ -415,6 +423,25 @@ def mla_axes(cfg):
     }
 
 
+def mla_softmax_scale(cfg) -> float:
+    """The MLA softmax scale: ``(qk_nope + qk_rope)^-0.5``, times YaRN's
+    ``mscale(factor, mscale_all_dim)^2`` where the rope is YaRN-scaled."""
+    from repro.models.layers import yarn_mscale
+
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(x, pos, cfg):
+    """Rotary embedding of MLA's rope dimensions (YaRN where configured)."""
+    from repro.models.layers import apply_rope
+
+    return apply_rope(x, pos, cfg.rope_theta, cfg.rope_scaling)
+
+
 def _mla_qkv_from_latent(p, ckv, krope, cfg):
     """Expand cached latent to per-head K/V. ckv: [..., lora], krope: [..., rope]."""
     k_nope = jnp.einsum("...l,lhn->...hn", ckv, p["wuk"])
@@ -427,22 +454,23 @@ def _mla_qkv_from_latent(p, ckv, krope, cfg):
 
 
 def mla_parallel(p, x, cfg, *, lens=None, pos0=0):
-    from repro.models.layers import apply_rope, rms_norm
+    from repro.models.layers import rms_norm
 
     b, s, _ = x.shape
     pos = jnp.arange(s) + pos0
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope, q_rope = jnp.split(q, [cfg.qk_nope_dim], axis=-1)
-    q_rope = apply_rope(q_rope, pos, cfg.rope_theta)
+    q_rope = _mla_rope(q_rope, pos, cfg)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
 
     dkv = jnp.einsum("bsd,dl->bsl", x, p["wdkv"])
     ckv, krope = jnp.split(dkv, [cfg.kv_lora_rank], axis=-1)
     ckv = rms_norm(ckv, p["kv_norm"], cfg.norm_eps)
-    krope = apply_rope(krope[:, :, None, :], pos, cfg.rope_theta)[:, :, 0]
+    krope = _mla_rope(krope[:, :, None, :], pos, cfg)[:, :, 0]
     k, v = _mla_qkv_from_latent(p, ckv, krope, cfg)
     q = shard(q, "batch", "seq", "heads", "qk_dim")
-    o = attend_parallel(q, k, v, causal=True, kv_valid_len=lens)
+    o = attend_parallel(q, k, v, causal=True, kv_valid_len=lens,
+                        scale=mla_softmax_scale(cfg))
     out = jnp.einsum("bshv,hvd->bsd", o, p["wo"])
     return out, (ckv, krope)
 
@@ -457,20 +485,20 @@ MLA_ABSORBED = True
 
 
 def mla_decode(p, x, cache_layer, cfg, *, absorbed: bool | None = None):
-    from repro.models.layers import apply_rope, rms_norm
+    from repro.models.layers import rms_norm
 
     if absorbed is None:
         absorbed = MLA_ABSORBED
     pos = cache_layer["pos"]
     q = jnp.einsum("bd,dhk->bhk", x, p["wq"])
     q_nope, q_rope = jnp.split(q, [cfg.qk_nope_dim], axis=-1)
-    q_rope = apply_rope(q_rope[:, None], pos[:, None], cfg.rope_theta)[:, 0]
+    q_rope = _mla_rope(q_rope[:, None], pos[:, None], cfg)[:, 0]
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
 
     dkv = jnp.einsum("bd,dl->bl", x, p["wdkv"])
     ckv_new, krope_new = jnp.split(dkv, [cfg.kv_lora_rank], axis=-1)
     ckv_new = rms_norm(ckv_new, p["kv_norm"], cfg.norm_eps)
-    krope_new = apply_rope(krope_new[:, None, None], pos[:, None], cfg.rope_theta)[:, 0, 0]
+    krope_new = _mla_rope(krope_new[:, None, None], pos[:, None], cfg)[:, 0, 0]
 
     m = cache_layer["ckv"].shape[1]
     slot = jnp.minimum(pos, m - 1)
@@ -480,7 +508,7 @@ def mla_decode(p, x, cache_layer, cfg, *, absorbed: bool | None = None):
     sp = jax.vmap(lambda v_, s, p_: v_.at[s].set(p_))(cache_layer["slot_pos"], slot, pos)
 
     valid = (sp >= 0) & (sp <= pos[:, None])
-    scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
+    scale = mla_softmax_scale(cfg)
     if absorbed:
         # scores = q_nope^T W_uk ckv + q_rope^T k_rope, all in latent space
         q_abs = jnp.einsum("bhn,lhn->bhl", q_nope, p["wuk"])  # [B,H,lora]
@@ -503,44 +531,63 @@ def mla_decode(p, x, cache_layer, cfg, *, absorbed: bool | None = None):
     return out, new_cache
 
 
+def latent_extend(ckv, krope, slot_pos, ckv_new, krope_new, pos0, lens_new):
+    """Write a block of new latents into an MLA cache at positions
+    pos0..pos0+len (no ring: MLA is full attention). ckv/krope: [B, M, *];
+    the new ones [B, Sn, *]. Returns (ckv, krope, slot_pos).
+
+    The new latents replace what the slots held: after the engine truncates
+    a cache (``slot_pos`` reset past the kept prefix) those slots still
+    hold the discarded tokens' latents."""
+    m = ckv.shape[1]
+    t = jnp.arange(ckv_new.shape[1])
+    pos = pos0[:, None] + t[None, :]
+    slot = jnp.minimum(pos, m - 1)
+    keep = t[None, :] < lens_new[:, None]
+
+    def row_fn(cc, kc, sp, cn, kn, sl, kp, p_row):
+        # clear the slots being written first (as `cache_extend` does), then
+        # scatter-add: each slot receives at most one kept position
+        hit = jnp.zeros((m,), bool).at[sl].set(kp, mode="drop")
+        cc = jnp.where(hit[:, None], jnp.zeros_like(cc), cc)
+        kc = jnp.where(hit[:, None], jnp.zeros_like(kc), kc)
+        sp = jnp.where(hit, -1, sp)
+        cc = cc.at[sl].add(jnp.where(kp[:, None], cn, 0.0))
+        kc = kc.at[sl].add(jnp.where(kp[:, None], kn, 0.0))
+        sp = sp.at[sl].max(jnp.where(kp, p_row, -1))
+        return cc, kc, sp
+
+    return jax.vmap(row_fn)(ckv, krope, slot_pos, ckv_new, krope_new, slot,
+                            keep, pos)
+
+
 def mla_extend(p, x, cache_layer, cfg, lens_new):
     """Multi-turn block extension for MLA latent caches. x: [B,Sn,D]."""
-    from repro.models.layers import apply_rope, rms_norm
+    from repro.models.layers import rms_norm
 
     b, sn, _ = x.shape
     pos0 = cache_layer["pos"]
     pos = pos0[:, None] + jnp.arange(sn)[None, :]
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope, q_rope = jnp.split(q, [cfg.qk_nope_dim], axis=-1)
-    q_rope = apply_rope(q_rope, pos, cfg.rope_theta)
+    q_rope = _mla_rope(q_rope, pos, cfg)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
 
     dkv = jnp.einsum("bsd,dl->bsl", x, p["wdkv"])
     ckv_new, krope_new = jnp.split(dkv, [cfg.kv_lora_rank], axis=-1)
     ckv_new = rms_norm(ckv_new, p["kv_norm"], cfg.norm_eps)
-    krope_new = apply_rope(krope_new[:, :, None, :], pos, cfg.rope_theta)[:, :, 0]
+    krope_new = _mla_rope(krope_new[:, :, None, :], pos, cfg)[:, :, 0]
 
     k_new, v_new = _mla_qkv_from_latent(p, ckv_new, krope_new, cfg)
     k_cache, v_cache = _mla_qkv_from_latent(p, cache_layer["ckv"],
                                             cache_layer["krope"], cfg)
     o = attend_mixed(q, k_new, v_new, k_cache, v_cache,
-                     cache_layer["slot_pos"], pos0, lens_new)
+                     cache_layer["slot_pos"], pos0, lens_new,
+                     scale=mla_softmax_scale(cfg))
 
-    # scatter new latents into the latent cache (no ring: MLA is full-attn)
-    m = cache_layer["ckv"].shape[1]
-    t = jnp.arange(sn)
-    slot = jnp.minimum(pos, m - 1)
-    keep = t[None, :] < lens_new[:, None]
-
-    def row_fn(cc, kc, sp, cn, kn, sl, kp, p_row):
-        cc = cc.at[sl].add(jnp.where(kp[:, None], cn, 0.0))
-        kc = kc.at[sl].add(jnp.where(kp[:, None], kn, 0.0))
-        sp = sp.at[sl].max(jnp.where(kp, p_row, -1))
-        return cc, kc, sp
-
-    ckv_c, kr_c, sp = jax.vmap(row_fn)(
+    ckv_c, kr_c, sp = latent_extend(
         cache_layer["ckv"], cache_layer["krope"], cache_layer["slot_pos"],
-        ckv_new, krope_new, slot, keep, pos)
+        ckv_new, krope_new, pos0, lens_new)
     out = jnp.einsum("bshv,hvd->bsd", o, p["wo"])
     new_cache = {"ckv": ckv_c, "krope": kr_c, "slot_pos": sp, "pos": pos0 + lens_new}
     return out, new_cache

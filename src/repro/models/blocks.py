@@ -53,8 +53,22 @@ def attn_block_axes(cfg, *, ffn_kind: str):
     return ax
 
 
-def attn_block_parallel(p, x, cfg, *, ffn_kind: str, lens=None, moe_mode="sort"):
-    """Returns (x, kv) where kv are the cacheables of this layer."""
+def block_ffn(p, x, cfg, ffn_kind: str, valid=None, layer=None):
+    """The block's second half: RMSNorm, then the dense SwiGLU or the MoE,
+    residual. ``valid`` marks the positions that route (MoE; None: all);
+    ``layer``: see `moe.moe_ffn`. Returns (x, stats): the MoE's int32[2]
+    (rows, experts) counts, or None for a dense block."""
+    with jax.named_scope(MLP_SCOPE):
+        h = rms_norm(x, p["ln2"], cfg.norm_eps)
+        if ffn_kind == "dense":
+            return x + ffn_apply(p["mlp"], h), None
+        out, stats = moe_mod.moe_ffn(p["moe"], h, cfg, valid, layer)
+        return x + out, stats
+
+
+def attn_block_parallel(p, x, cfg, *, ffn_kind: str, lens=None, layer=None):
+    """Returns (x, kv, stats): kv are the cacheables of this layer, stats
+    `block_ffn`'s."""
     with jax.named_scope(ATTN_SCOPE):
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
         if cfg.attn_kind == "mla":
@@ -62,16 +76,14 @@ def attn_block_parallel(p, x, cfg, *, ffn_kind: str, lens=None, moe_mode="sort")
         else:
             o, kv = attn.gqa_parallel(p["attn"], h, cfg, lens=lens)
         x = _res(x + _res(o))
-    with jax.named_scope(MLP_SCOPE):
-        h = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if ffn_kind == "dense":
-            x = x + ffn_apply(p["mlp"], h)
-        else:
-            x = x + moe_mod.moe_ffn(p["moe"], h, cfg, mode=moe_mode)
-    return _res(x), kv
+    valid = None if lens is None else (
+        jnp.arange(x.shape[1])[None, :] < lens[:, None])
+    x, stats = block_ffn(p, x, cfg, ffn_kind, valid, layer)
+    return _res(x), kv, stats
 
 
-def attn_block_decode(p, x, cache_layer, cfg, *, ffn_kind: str, moe_mode="sort"):
+def attn_block_decode(p, x, cache_layer, cfg, *, ffn_kind: str, layer=None):
+    """One token per row. Returns (x, new cache layer, stats)."""
     with jax.named_scope(ATTN_SCOPE):
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
         if cfg.attn_kind == "mla":
@@ -79,14 +91,8 @@ def attn_block_decode(p, x, cache_layer, cfg, *, ffn_kind: str, moe_mode="sort")
         else:
             o, new_cache = attn.gqa_decode(p["attn"], h, cache_layer, cfg)
         x = x + o
-    with jax.named_scope(MLP_SCOPE):
-        h = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if ffn_kind == "dense":
-            x = x + ffn_apply(p["mlp"], h)
-        else:
-            x = x + moe_mod.moe_ffn(p["moe"], h[:, None, :], cfg,
-                                    mode=moe_mode)[:, 0]
-    return x, new_cache
+    x, stats = block_ffn(p, x, cfg, ffn_kind, layer=layer)
+    return x, new_cache, stats
 
 
 # ---------------- RWKV6 block ----------------

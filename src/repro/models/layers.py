@@ -7,6 +7,8 @@ for each parameter live in a mirror pytree of space-separated axis strings
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -39,13 +41,48 @@ def rope_freqs(head_dim: int, theta: float):
     return theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
 
 
-def apply_rope(x, pos, theta: float):
-    """x: [..., seq, heads, head_dim] (llama half-rotation), pos: [..., seq]."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term ``0.1 * mscale * ln(factor) + 1``
+    (1 where the context is not stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(head_dim: int, theta: float, scaling) -> tuple:
+    """The rotary pairs between which YaRN ramps from the original to the
+    stretched frequency: pairs below ``low`` keep theirs (more than
+    ``beta_fast`` rotations over the original length), pairs from ``high``
+    on are divided by ``factor`` (fewer than ``beta_slow``)."""
+    def dim(rotations):
+        return (head_dim * math.log(scaling.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(dim(scaling.beta_slow)), head_dim - 1)
+    return low, high
+
+
+def yarn_inv_freq(head_dim: int, theta: float, scaling):
+    """The rotary frequencies under YaRN scaling [head_dim / 2]."""
+    low, high = yarn_correction_range(head_dim, theta, scaling)
+    extra = rope_freqs(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / scaling.factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope(x, pos, theta: float, scaling=None):
+    """x: [..., seq, heads, head_dim] (llama half-rotation), pos: [..., seq].
+    ``scaling``: a `configs.base.RopeScaling` (YaRN) or None."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)  # [d/2]
+    freqs = (rope_freqs(d, theta) if scaling is None
+             else yarn_inv_freq(d, theta, scaling))  # [d/2]
     angles = pos[..., None].astype(jnp.float32) * freqs  # [..., seq, d/2]
     cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        cos, sin = cos * m, sin * m
     x1 = x[..., : d // 2].astype(jnp.float32)
     x2 = x[..., d // 2 :].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)

@@ -36,7 +36,7 @@ def _make_stacks(cfg) -> list[StackSpec]:
         nd = cfg.first_dense_layers
         stacks = []
         if nd:
-            stacks.append(StackSpec(nd, "dense", cfg.dense_d_ff or cfg.d_ff))
+            stacks.append(StackSpec(nd, "dense", cfg.d_ff))
         stacks.append(StackSpec(cfg.n_layers - nd, "moe", cfg.moe_d_ff or cfg.d_ff))
         return stacks
     return [StackSpec(cfg.n_layers, "dense", cfg.d_ff)]
@@ -131,16 +131,26 @@ def build_lm(cfg):
         x = _embed_inputs(params, batch)
         parts = {}
         if family == "attn":
+            stats = []
             for i, spec in enumerate(stacks):
-                def body(carry, p_l, _spec=spec):
-                    y, kv = blk.attn_block_parallel(p_l, carry, cfg,
-                                                    ffn_kind=_spec.ffn_kind,
-                                                    lens=lens)
-                    return y, (kv if collect else None)
+                sliced, experts = _unstack_experts(params[f"stack{i}"])
+
+                def body(carry, xs, _spec=spec, _experts=experts):
+                    p_l, l = xs
+                    y, kv, st = blk.attn_block_parallel(
+                        _with_experts(p_l, _experts), carry, cfg,
+                        ffn_kind=_spec.ffn_kind, lens=lens,
+                        layer=None if _experts is None else l)
+                    return y, (kv if collect else None, st)
                 f = jax.checkpoint(body) if remat else body
-                x, kvs = layer_scan(f, x, params[f"stack{i}"])
+                x, (kvs, st) = layer_scan(
+                    f, x, (sliced, jnp.arange(spec.n_layers)))
                 if collect:
                     parts[f"stack{i}"] = kvs
+                if st is not None:
+                    stats.append(st.sum(axis=0))
+            if stats:
+                parts["moe_stats"] = sum(stats)
         elif family == "rwkv":
             def body(carry, xs):
                 p_l, st = xs
@@ -203,6 +213,9 @@ def build_lm(cfg):
         return next_token_loss(logits, targets)
 
     # ---------------- caches ----------------
+    # A MoE model's cache also carries ``moe_stats``: the int32[2] (rows,
+    # experts) its routed experts computed, summed over layers, in the call
+    # that returned the cache (a prefill, an extend or one decode step).
     def init_cache(b: int, max_len: int):
         m = min(cfg.sliding_window, max_len) if cfg.sliding_window else max_len
         pos = jnp.zeros((b,), jnp.int32)
@@ -211,6 +224,8 @@ def build_lm(cfg):
             for i, spec in enumerate(stacks):
                 c[f"stack{i}"] = _attn_stack_cache(cfg, spec, b, m, dtype)
             c["slot_pos"] = jnp.full((b, m), -1, jnp.int32)
+            if cfg.is_moe:
+                c["moe_stats"] = jnp.zeros((2,), jnp.int32)
             return c
         if family == "rwkv":
             return {"pos": pos,
@@ -241,6 +256,8 @@ def build_lm(cfg):
 
         cache = init_cache(b, max_len)
         cache["pos"] = lens
+        if "moe_stats" in parts:
+            cache["moe_stats"] = parts["moe_stats"]
         if family == "attn":
             m = cache["slot_pos"].shape[1]
             for i, spec in enumerate(stacks):
@@ -303,25 +320,31 @@ def build_lm(cfg):
         new_cache = dict(cache)
         if family == "attn":
             sp_out = cache["slot_pos"]
+            work = jnp.zeros((2,), jnp.int32) if cfg.is_moe else None
             for i, spec in enumerate(stacks):
                 st_cache = cache[f"stack{i}"]
-                pstack = params[f"stack{i}"]
+                pstack, experts = _unstack_experts(params[f"stack{i}"])
                 keys = ("ckv", "krope") if cfg.attn_kind == "mla" else ("k", "v")
 
-                def body(l, carry, _spec=spec, _pstack=pstack, _keys=keys):
-                    y, st, sp = carry
-                    p_l = _slice_l(_pstack, l)
+                def body(l, carry, _spec=spec, _pstack=pstack, _keys=keys,
+                         _experts=experts):
+                    y, st, sp, w = carry
+                    p_l = _with_experts(_slice_l(_pstack, l), _experts)
                     cl = dict(zip(_keys, (_slice_l(st[kk], l) for kk in _keys)))
                     cl.update(slot_pos=cache["slot_pos"], pos=pos)
-                    y, nc = blk.attn_block_decode(p_l, y, cl, cfg,
-                                                  ffn_kind=_spec.ffn_kind)
+                    y, nc, stats = blk.attn_block_decode(
+                        p_l, y, cl, cfg, ffn_kind=_spec.ffn_kind,
+                        layer=None if _experts is None else l)
                     st = {kk: _put_l(st[kk], nc[kk], l) for kk in _keys}
-                    return (y, st, nc["slot_pos"])
+                    return (y, st, nc["slot_pos"],
+                            w if stats is None else w + stats)
 
-                x, st_new, sp_out = indexed_layer_loop(
-                    spec.n_layers, body, (x, dict(st_cache), sp_out))
+                x, st_new, sp_out, work = indexed_layer_loop(
+                    spec.n_layers, body, (x, dict(st_cache), sp_out, work))
                 new_cache[f"stack{i}"] = st_new
             new_cache["slot_pos"] = sp_out
+            if cfg.is_moe:
+                new_cache["moe_stats"] = work
         elif family == "rwkv":
             def body(l, carry):
                 y, states = carry
@@ -392,11 +415,16 @@ def build_lm(cfg):
         new_cache = dict(cache)
         if family == "attn":
             sp_out = cache["slot_pos"]
+            valid = jnp.arange(tokens.shape[1])[None, :] < lens_new[:, None]
+            stats = []
             for i, spec in enumerate(stacks):
                 st_cache = cache[f"stack{i}"]
+                sliced, experts = _unstack_experts(params[f"stack{i}"])
+                layers = jnp.arange(spec.n_layers)
                 if cfg.attn_kind == "mla":
-                    def body(carry, xs, _spec=spec):
-                        p_l, ckv_l, kr_l = xs
+                    def body(carry, xs, _spec=spec, _experts=experts):
+                        p_l, ckv_l, kr_l, l = xs
+                        p_l = _with_experts(p_l, _experts)
                         with jax.named_scope(blk.ATTN_SCOPE):
                             h = rms_norm(carry, p_l["ln1"], cfg.norm_eps)
                             cl = {"ckv": ckv_l, "krope": kr_l,
@@ -404,16 +432,19 @@ def build_lm(cfg):
                             o, nc = attn.mla_extend(p_l["attn"], h, cl, cfg,
                                                     lens_new)
                             y = carry + o
-                        y = _block_ffn(p_l, y, cfg, _spec.ffn_kind)
-                        return y, (nc["ckv"], nc["krope"], nc["slot_pos"])
-                    x, (ckv_n, kr_n, sp_n) = layer_scan(
-                        body, x, (params[f"stack{i}"], st_cache["ckv"],
-                                  st_cache["krope"]))
+                        y, st = blk.block_ffn(
+                            p_l, y, cfg, _spec.ffn_kind, valid,
+                            None if _experts is None else l)
+                        return y, (nc["ckv"], nc["krope"], nc["slot_pos"], st)
+                    x, (ckv_n, kr_n, sp_n, st) = layer_scan(
+                        body, x, (sliced, st_cache["ckv"], st_cache["krope"],
+                                  layers))
                     new_cache[f"stack{i}"] = {"ckv": ckv_n, "krope": kr_n}
                     sp_out = sp_n[0]
                 else:
-                    def body(carry, xs, _spec=spec):
-                        p_l, k_l, v_l = xs
+                    def body(carry, xs, _spec=spec, _experts=experts):
+                        p_l, k_l, v_l, l = xs
+                        p_l = _with_experts(p_l, _experts)
                         with jax.named_scope(blk.ATTN_SCOPE):
                             h = rms_norm(carry, p_l["ln1"], cfg.norm_eps)
                             cl = {"k": k_l, "v": v_l,
@@ -421,14 +452,20 @@ def build_lm(cfg):
                             o, nc = attn.gqa_extend(p_l["attn"], h, cl, cfg,
                                                     lens_new)
                             y = carry + o
-                        y = _block_ffn(p_l, y, cfg, _spec.ffn_kind)
-                        return y, (nc["k"], nc["v"], nc["slot_pos"])
-                    x, (k_n, v_n, sp_n) = layer_scan(
-                        body, x, (params[f"stack{i}"], st_cache["k"],
-                                  st_cache["v"]))
+                        y, st = blk.block_ffn(
+                            p_l, y, cfg, _spec.ffn_kind, valid,
+                            None if _experts is None else l)
+                        return y, (nc["k"], nc["v"], nc["slot_pos"], st)
+                    x, (k_n, v_n, sp_n, st) = layer_scan(
+                        body, x, (sliced, st_cache["k"], st_cache["v"],
+                                  layers))
                     new_cache[f"stack{i}"] = {"k": k_n, "v": v_n}
                     sp_out = sp_n[0]
+                if st is not None:
+                    stats.append(st.sum(axis=0))
             new_cache["slot_pos"] = sp_out
+            if stats:
+                new_cache["moe_stats"] = sum(stats)
         elif family == "rwkv":
             batch = {"tokens": tokens}
             x, parts = forward(params, batch, remat=False, collect=True,
@@ -450,15 +487,25 @@ def build_lm(cfg):
     }
 
 
-def _block_ffn(p_l, y, cfg, ffn_kind):
-    from repro.models import moe as moe_mod
-    from repro.models.layers import ffn_apply
+def _unstack_experts(pstack):
+    """A stack's params to slice layer by layer, and a MoE stack's routed
+    expert weights kept whole (None for a dense stack): the grouped expert
+    kernel reads one layer of them in place, where a slice would copy the
+    layer's experts on every call."""
+    from repro.models.moe import EXPERT_WEIGHTS
 
-    with jax.named_scope(blk.MLP_SCOPE):
-        h = rms_norm(y, p_l["ln2"], cfg.norm_eps)
-        if ffn_kind == "dense":
-            return y + ffn_apply(p_l["mlp"], h)
-        return y + moe_mod.moe_ffn(p_l["moe"], h, cfg)
+    if "moe" not in pstack:
+        return pstack, None
+    moe = pstack["moe"]
+    rest = {k: v for k, v in moe.items() if k not in EXPERT_WEIGHTS}
+    return {**pstack, "moe": rest}, {k: moe[k] for k in EXPERT_WEIGHTS}
+
+
+def _with_experts(p_l, experts):
+    """One layer's params with the stack's whole expert weights put back."""
+    if experts is None:
+        return p_l
+    return {**p_l, "moe": {**p_l["moe"], **experts}}
 
 
 def _attn_stack_cache(cfg, spec, b, m, dtype):

@@ -1,24 +1,27 @@
-"""Mixture-of-Experts FFN with capacity-bounded, sort-based dispatch.
+"""Dropless Mixture-of-Experts FFN: softmax gate, greedy top-k, grouped
+expert matmul.
 
-TPU adaptation (see DESIGN.md §3): instead of CUDA grouped-GEMM/ragged
-dispatch, tokens are bucketed per expert with a *row-local* argsort (no
-cross-device sort) and experts run as one batched einsum over [E, C, D]
-buckets — MXU-friendly and exact up to capacity drops. Dropped tokens
-pass through the residual stream (standard GShard semantics).
+Every (token, expert) pair the gate picks is computed; none is dropped, so
+a token's output depends on its own routing only, not on what it is
+batched with. The rows are sorted by expert and the routed experts' SwiGLU
+runs as one grouped matmul over the experts that got rows
+(`repro.kernels.moe_gmm`): a decode step reads ``top_k`` experts' weights,
+not all of them. Positions marked invalid (the padding of a bucketed
+prefill or extend) send no row to any expert.
 
-Two dispatch modes:
-  * ``sort``   (default): gather-based, no one-hot matmuls, flops ~ k/E of
-    the dense-all-experts lowering.
-  * ``onehot``: GShard einsum dispatch, kept for comparison in §Perf.
+The gate follows the published DeepSeek/Mixtral form: float32 router
+logits and softmax, top-k, the k weights renormalised to sum 1 only where
+``cfg.norm_topk_prob``, then times ``cfg.routed_scaling_factor``. Shared
+experts (DeepSeek) are one SwiGLU of width ``n_shared_experts * moe_d_ff``
+that every token takes.
 """
 from __future__ import annotations
-
-import math
 
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import shard
+from repro.kernels.moe_gmm import moe_gmm
+from repro.kernels.ref import moe_gmm_ref
 from repro.models.layers import normal_init
 
 
@@ -56,106 +59,78 @@ def moe_axes(cfg):
     return ax
 
 
-def _capacity(s: int, k: int, e: int, cf: float) -> int:
-    return max(1, int(math.ceil(s * k / e * cf)))
+def route(p, x, cfg):
+    """The gate. x: [N, D] -> (weights [N, k] float32, experts [N, k]).
+    Its projection runs in float32 at full precision, as the published
+    gate computes it: the top-k choice is a discontinuity, and rounding
+    its logits to the activations' dtype would flip near ties."""
+    logits = jnp.einsum("nd,de->ne", x.astype(jnp.float32),
+                        p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    weights, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                     cfg.top_k)
+    if cfg.norm_topk_prob:
+        weights = weights / weights.sum(-1, keepdims=True)
+    return weights * cfg.routed_scaling_factor, experts
 
 
-def _route(p, x, cfg):
-    """Router: top-k normalized gates. x: [B,S,D] -> (gates, idx) [B,S,k]."""
-    logits = jnp.einsum("bsd,de->bse", x, p["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, cfg.top_k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-    return gates, idx
+#: the routed experts' weights, which a stacked layer keeps whole
+EXPERT_WEIGHTS = ("wg", "wu", "wd")
 
 
-def _expert_ffn(p, xe):
-    """xe: [B, E, C, D] -> [B, E, C, D]."""
-    h = jax.nn.silu(jnp.einsum("becd,edf->becf", xe, p["wg"]))
-    h = h * jnp.einsum("becd,edf->becf", xe, p["wu"])
-    h = shard(h, "batch", "expert", "expert_capacity", "ff")
-    return jnp.einsum("becf,efd->becd", h, p["wd"])
+@jax.custom_vjp
+def _experts(xs, sizes, wg, wu, wd, layer):
+    return moe_gmm(xs, sizes, wg, wu, wd, layer)
 
 
-def moe_ffn_sort(p, x, cfg):
-    """Gather-based dispatch, row-local capacity. x: [B, S, D]."""
-    b, s, d = x.shape
+def _experts_fwd(xs, sizes, wg, wu, wd, layer):
+    return moe_gmm(xs, sizes, wg, wu, wd, layer), (xs, sizes, wg, wu, wd,
+                                                   layer)
+
+
+def _experts_bwd(res, g):
+    # the kernel has no autodiff rule: the gradients are its oracle's
+    xs, sizes, wg, wu, wd, layer = res
+
+    def ref(a, b, c, d):
+        if layer is not None:
+            b, c, d = b[layer], c[layer], d[layer]
+        return moe_gmm_ref(a, sizes, b, c, d)
+
+    dx, dwg, dwu, dwd = jax.vjp(ref, xs, wg, wu, wd)[1](g)
+    return dx, None, dwg, dwu, dwd, None
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
+
+
+def moe_ffn(p, x, cfg, valid=None, layer=None):
+    """x: [..., D]; ``valid`` (x's leading shape, bool) marks the positions
+    that route (None: all). Where ``layer`` is given, the routed experts'
+    weights (``EXPERT_WEIGHTS``) carry the stack's leading layer axis and
+    ``layer`` picks this one (the kernel reads it in place). Returns (out
+    [..., D], stats int32[2]): the (token, expert) rows the routed experts
+    computed and the experts that got at least one."""
+    lead, d = x.shape[:-1], x.shape[-1]
     e, k = cfg.n_experts, cfg.top_k
-    c = _capacity(s, k, e, cfg.capacity_factor)
-    gates, idx = _route(p, x, cfg)  # [B,S,k]
-
-    flat_idx = idx.reshape(b, s * k)  # expert of each (token, slot)
-    flat_gate = gates.reshape(b, s * k)
-
-    # rank of each (token,slot) within its expert, per row
-    order = jnp.argsort(flat_idx, axis=-1, stable=True)  # [B, S*k]
-    sorted_e = jnp.take_along_axis(flat_idx, order, axis=-1)
-    onehot = jax.nn.one_hot(flat_idx, e, dtype=jnp.int32)  # [B,S*k,E]
-    counts = onehot.sum(axis=1)  # [B,E]
-    starts = jnp.cumsum(counts, axis=-1) - counts  # exclusive
-    rank = jnp.arange(s * k)[None, :] - jnp.take_along_axis(starts, sorted_e, axis=-1)
-    ok = rank < c
-    dest = jnp.where(ok, sorted_e * c + rank, e * c)  # overflow slot
-
-    # invert: destination bucket slot of each flat (token,slot)
-    dest_of_flat = jnp.zeros((b, s * k), jnp.int32)
-    dest_of_flat = jax.vmap(lambda dof, o, de: dof.at[o].set(de))(dest_of_flat, order, dest)
-
-    token_of_sorted = order // k  # token index of each sorted slot
-    # bucket -> source token (E*C + 1 with dummy overflow row)
-    src = jnp.full((b, e * c + 1), s, jnp.int32)
-    src = jax.vmap(lambda sr, de, to: sr.at[de].set(to))(src, dest, token_of_sorted)
-    x_pad = jnp.concatenate([x, jnp.zeros((b, 1, d), x.dtype)], axis=1)
-    xe = jax.vmap(lambda xp, sr: xp[sr])(x_pad, src[:, : e * c])  # [B, E*C, D]
-    xe = xe.reshape(b, e, c, d)
-    xe = shard(xe, "batch", "expert", "expert_capacity", "embed")
-
-    ye = _expert_ffn(p, xe).reshape(b, e * c, d)
-    ye = jnp.concatenate([ye, jnp.zeros((b, 1, d), ye.dtype)], axis=1)
-    contrib = jax.vmap(lambda yp, df: yp[df])(ye, dest_of_flat)  # [B,S*k,D]
-    out = (contrib.reshape(b, s, k, d)
-           * flat_gate.reshape(b, s, k, 1).astype(contrib.dtype)).sum(axis=2)
-
+    xt = x.reshape(-1, d)
+    weights, experts = route(p, xt, cfg)            # [N, k]
+    flat = experts.reshape(-1)                      # row r = token r // k
+    if valid is not None:
+        flat = jnp.where(jnp.repeat(valid.reshape(-1), k), flat, e)
+    # rows sorted by expert; invalid rows (expert e) sort last and join
+    # no group
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1, mode="drop")
+    ys = _experts(xt[order // k], sizes, p["wg"], p["wu"], p["wd"], layer)
+    # back to (token, slot) order: a row gather by the inverse permutation
+    inv = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0]))
+    y = ys[inv]
+    out = jnp.einsum("nkd,nk->nd", y.reshape(-1, k, d).astype(jnp.float32),
+                     weights).astype(x.dtype)
     if cfg.n_shared_experts:
         sp = p["shared"]
-        h = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, sp["wg"]))
-        h = h * jnp.einsum("bsd,df->bsf", x, sp["wu"])
-        out = out + jnp.einsum("bsf,fd->bsd", h, sp["wd"])
-    return out
-
-
-def moe_ffn_onehot(p, x, cfg):
-    """GShard einsum dispatch (comparison path for §Perf)."""
-    b, s, d = x.shape
-    e, k = cfg.n_experts, cfg.top_k
-    c = _capacity(s, k, e, cfg.capacity_factor)
-    gates, idx = _route(p, x, cfg)
-
-    # position-in-expert via cumulative sums over sequence, per k-slot
-    out = jnp.zeros_like(x)
-    dispatch = jnp.zeros((b, s, e, c), x.dtype)
-    combine = jnp.zeros((b, s, e, c), jnp.float32)
-    prev = jnp.zeros((b, e), jnp.int32)
-    for slot in range(k):
-        oh = jax.nn.one_hot(idx[:, :, slot], e, dtype=jnp.int32)  # [B,S,E]
-        pos = jnp.cumsum(oh, axis=1) - 1 + prev[:, None, :]
-        prev = prev + oh.sum(axis=1)
-        ok = (pos < c) & (oh > 0)
-        pc = jax.nn.one_hot(jnp.where(ok, pos, c), c + 1, dtype=x.dtype)[..., :c]
-        dispatch = dispatch + oh.astype(x.dtype)[..., None] * pc
-        combine = combine + (gates[:, :, slot][..., None, None]
-                             * oh.astype(jnp.float32)[..., None] * pc.astype(jnp.float32))
-    xe = jnp.einsum("bsec,bsd->becd", dispatch, x)
-    ye = _expert_ffn(p, xe)
-    out = jnp.einsum("bsec,becd->bsd", combine.astype(x.dtype), ye)
-
-    if cfg.n_shared_experts:
-        sp = p["shared"]
-        h = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, sp["wg"]))
-        h = h * jnp.einsum("bsd,df->bsf", x, sp["wu"])
-        out = out + jnp.einsum("bsf,fd->bsd", h, sp["wd"])
-    return out
-
-
-def moe_ffn(p, x, cfg, mode: str = "sort"):
-    return moe_ffn_sort(p, x, cfg) if mode == "sort" else moe_ffn_onehot(p, x, cfg)
+        h = jax.nn.silu(xt @ sp["wg"]) * (xt @ sp["wu"])
+        out = out + h @ sp["wd"]
+    stats = jnp.stack([sizes.sum(), (sizes > 0).sum()]).astype(jnp.int32)
+    return out.reshape(*lead, d), stats

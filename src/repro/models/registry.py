@@ -126,4 +126,6 @@ def cache_axes(model: Model):
             ax[f"stack{i}"] = {
                 "k": "layers batch cache_seq kv_heads head_dim",
                 "v": "layers batch cache_seq kv_heads head_dim"}
+    if cfg.is_moe:
+        ax["moe_stats"] = "."
     return ax

@@ -67,26 +67,44 @@ class ServeResult:
 _SHARED: dict = {}
 
 
+#: the cache entry in which a MoE model's calls return their expert work
+#: (int32 [rows, experts] of the call; `repro.models.lm`)
+MOE_STATS = "moe_stats"
+
+
 def _greedy(decode_step):
     """The greedy decode loop as one program: ``generate(params, cache,
     logits, n, size)`` takes the first token from ``logits``, then runs
     ``n`` (traced) decode steps, each writing its input token into a
     ``size``-token (static) output buffer and taking the next token from
     the step's logits. Returns (tokens [size], cache): the cache holds every
-    generated token; the last step's logits are discarded."""
+    generated token (and, for a MoE model, the expert work of all ``n``
+    steps); the last step's logits are discarded."""
     def generate(params, cache, logits, n, size):
         def step(i, carry):
-            tok, cache, out = carry
+            tok, cache, out, work = carry
             out = out.at[i].set(tok[0])
             logits, cache = decode_step(params, cache, tok)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache, out
+            if work is not None:
+                work = work + cache[MOE_STATS]
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32), cache, out,
+                    work)
 
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        _, cache, out = jax.lax.fori_loop(
-            0, n, step, (tok, cache, jnp.zeros((size,), jnp.int32)))
+        work = (jnp.zeros_like(cache[MOE_STATS]) if MOE_STATS in cache
+                else None)
+        _, cache, out, work = jax.lax.fori_loop(
+            0, n, step, (tok, cache, jnp.zeros((size,), jnp.int32), work))
+        if work is not None:
+            cache = {**cache, MOE_STATS: work}
         return out, cache
 
     return generate
+
+
+def _moe_counters(work) -> dict:
+    """A span's MoE counters from a call's int32 [rows, experts]."""
+    return {"moe_rows": int(work[0]), "moe_experts": int(work[1])}
 
 
 def _shared_fns(cfg: ModelConfig, max_len: int):
@@ -217,7 +235,11 @@ class AgentEngine:
         ``n_hit``, ``n_gen``, ``evicted``); its self time is the host
         preparation around the ``engine.prefill``/``engine.extend`` and
         ``engine.decode`` (counters ``steps``, the decode steps run, and
-        ``syncs``, its device-to-host reads) spans."""
+        ``syncs``, its device-to-host reads) spans. A MoE model's
+        prefill/extend and decode spans also count ``moe_rows``, the
+        (token, expert) rows its routed experts computed, and
+        ``moe_experts``, the experts that got at least one row, each summed
+        over layers and steps."""
         prof = self.profiler
         evictions = self.evictions
         with phase_scope(prof, "engine.serve", session=dialogue_id,
@@ -252,20 +274,29 @@ class AgentEngine:
 
         t0 = time.perf_counter()
         with phase_scope(prof, "engine.prefill" if mode == "fresh"
-                         else "engine.extend"):
-            logits, cache = self._first_logits(prompt, sess, mode, n_hit)
+                         else "engine.extend") as first:
+            logits, cache, work = self._first_logits(prompt, sess, mode,
+                                                     n_hit)
             jax.block_until_ready(logits)
             t_first = time.perf_counter()
+            if work is not None:
+                # the program has finished: this reads its ready counters
+                # (a span takes its counters only while it is open)
+                first.set(**_moe_counters(np.asarray(work)))
 
-        # greedy decode: one program, its tokens read back once (the
-        # output buffer's size is bucketed so few sizes compile)
+        # greedy decode: one program, its tokens (and a MoE model's expert
+        # counters) read back once (the output buffer's size is bucketed so
+        # few sizes compile)
         with phase_scope(prof, "engine.decode") as decode:
             out, cache = jax.block_until_ready(self._generate_j(
                 self.params, cache, logits, np.int32(max_new),
                 size=_bucket(max_new, lo=8)))
-            gen = np.asarray(out)[:max_new]
+            out, work = jax.device_get((out, cache.get(MOE_STATS)))
+            gen = out[:max_new]
             t_end = time.perf_counter()
             decode.set(steps=max_new, syncs=1)
+            if work is not None:
+                decode.set(**_moe_counters(work))
 
         # store the state covering prompt + generated answer (next turn will
         # extend past it, mirroring vLLM prefix caching)
@@ -282,19 +313,21 @@ class AgentEngine:
                       n_hit: int):
         """The path to the first token's logits: a fresh prefill, an
         extend past the ``n_hit`` cached tokens, or (everything cached) a
-        probe step on the truncated cache.  Returns (logits, cache)."""
+        probe step on the truncated cache.  Returns (logits, cache, work):
+        ``work`` is a MoE model's expert counters of the call that made the
+        logits (None for other models)."""
         if mode == "identical":
             # nothing to prefill; just decode from current state
             cache = sess.cache
-            logits, _ = self._decode_noop(cache)
-            return logits, cache
+            logits, probe = self._decode_noop(cache)
+            return logits, cache, probe.get(MOE_STATS)
         if mode == "extend":
             cache = sess.cache
             if not self.recurrent:
                 cache = self._truncate_attn_cache(cache, n_hit)
             if n_hit == len(prompt):
-                logits, _ = self._decode_noop(cache)
-                return logits, cache
+                logits, probe = self._decode_noop(cache)
+                return logits, cache, probe.get(MOE_STATS)
             suffix = prompt[n_hit:]
             if self.recurrent:
                 # recurrent state cannot mask padding: exact-length extend
@@ -303,9 +336,11 @@ class AgentEngine:
             else:
                 pad = np.zeros(_bucket(len(suffix)), np.int32)
                 pad[: len(suffix)] = suffix
-            return self._extend_j(self.params, cache,
-                                  jnp.asarray(pad[None]),
-                                  jnp.asarray([len(suffix)], jnp.int32))
+            logits, cache = self._extend_j(self.params, cache,
+                                           jnp.asarray(pad[None]),
+                                           jnp.asarray([len(suffix)],
+                                                       jnp.int32))
+            return logits, cache, cache.get(MOE_STATS)
         n_prompt = len(prompt)
         if self.recurrent:
             pad = prompt
@@ -317,7 +352,8 @@ class AgentEngine:
         if self.cfg.is_encdec:
             batch["frames"] = jnp.zeros((1, self.cfg.src_len,
                                          self.cfg.d_model), jnp.float32)
-        return self._prefill_j(self.params, batch)
+        logits, cache = self._prefill_j(self.params, batch)
+        return logits, cache, cache.get(MOE_STATS)
 
     def _decode_noop(self, cache):
         """Cheap logits for the 'everything cached' path: one decode step on

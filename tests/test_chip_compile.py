@@ -109,3 +109,54 @@ def test_qwen3_8b_layer_prefill_fits_one_chip(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < used < HBM_BYTES
+
+
+@pytest.mark.parametrize("rows", [6, 96, 3072])
+def test_moe_gmm_compiles_to_the_kernel(one_chip, rows):
+    """The grouped expert kernel at DeepSeek-V2-Lite's widths (64 experts,
+    hidden 2048, expert width 1408): a decode step's 6 rows, an extend's
+    and a 512-token prefill's; the kernel is the program's ``moe_gmm``."""
+    from repro.kernels.moe_gmm import moe_gmm
+
+    e, d, f = 64, 2048, 1408
+    compiled = jax.jit(lambda x, s, wg, wu, wd: moe_gmm(
+        x, s, wg, wu, wd, interpret=False)).lower(
+        _spec(one_chip, (rows, d), jnp.bfloat16),
+        _spec(one_chip, (e,), jnp.int32),
+        _spec(one_chip, (e, d, f), jnp.bfloat16),
+        _spec(one_chip, (e, d, f), jnp.bfloat16),
+        _spec(one_chip, (e, f, d), jnp.bfloat16)).compile()
+    assert "%moe_gmm" in compiled.as_text()
+
+
+def test_deepseek_v2_lite_moe_layer_prefill_fits_one_chip(one_chip,
+                                                         monkeypatch):
+    """One MoE layer of deepseek-v2-lite at its published widths (MLA with
+    YaRN, 64 routed experts of width 1408 + 2 shared, bf16) over a
+    512-token prompt bucket. The CPU backend would pick the kernel's
+    interpret mode: the compiled kernel is asked for here."""
+    from repro.configs import get_config
+    from repro.kernels import moe_gmm
+    from repro.models import blocks
+
+    monkeypatch.setattr(moe_gmm, "resolve_interpret",
+                        lambda interpret: bool(interpret))
+
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b"), n_layers=2)
+    dtype = jnp.dtype(cfg.dtype)
+    layer = jax.eval_shape(lambda k: blocks.attn_block_init(
+        k, cfg, dtype, ffn_kind="moe"), jax.random.PRNGKey(0))
+    layer = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), layer)
+
+    def prefill_layer(p, x, lens):
+        return blocks.attn_block_parallel(p, x, cfg, ffn_kind="moe",
+                                          lens=lens)
+
+    compiled = jax.jit(prefill_layer).lower(
+        layer, _spec(one_chip, (1, 512, cfg.d_model), dtype),
+        _spec(one_chip, (1,), jnp.int32)).compile()
+    assert "%moe_gmm" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert 0 < used < HBM_BYTES

@@ -41,7 +41,7 @@ def test_generate_matches_per_step_loop(engine, max_new):
     engine.drop_session("s")
     res = engine.serve("s", prompt, max_new_tokens=max_new)
     # the oracle: the first logits, then the per-step decode + argmax
-    logits, cache = engine._first_logits(prompt, None, "fresh", 0)
+    logits, cache, _ = engine._first_logits(prompt, None, "fresh", 0)
     want = []
     tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     for _ in range(max_new):

@@ -30,12 +30,15 @@ TIER1_MODULES = {
     "test_marker_audit",
     "test_mcmf",
     "test_mechanism",
+    "test_mla",
     "test_models",
+    "test_moe",
     "test_placement",
     "test_predictor_batch",
     "test_reputation_identity",
     "test_routing_fused",
     "test_run_workload",
+    "test_served_deepseek",
     "test_sharding",
     "test_simulator",
     "test_system",

@@ -13,10 +13,7 @@ KEY = jax.random.PRNGKey(0)
 
 
 def _reduced(name):
-    red = get_config(name).scaled(dtype="float32")
-    if red.is_moe:  # no-drop capacity so batched/stepwise paths agree
-        red = dataclasses.replace(red, capacity_factor=float(red.n_experts))
-    return red
+    return get_config(name).scaled(dtype="float32")
 
 
 def _batch(red, b, s, key=KEY):
