@@ -23,7 +23,9 @@ from a roofline model calibrated against the real engines, enabling the
 config at full width) in place of the per-class reduced ones. Real engines
 are placed round-robin over ``jax.devices()`` (`agent_device`), so on a
 four-chip host each agent's parameters, caches and jitted calls sit on its
-own chip.
+own chip. `SimCluster.dispatch` serves one route batch: where the agents'
+weights sit on several devices, one worker thread a device serves that
+device's requests while the others' run, all on one virtual clock.
 
 `run_workload` below is the closed-loop, fixed-population oracle loop; the
 event-driven open-loop driver for scale runs lives in
@@ -33,9 +35,13 @@ decisions bit-for-bit under synchronous arrivals (tests/test_simulator.py).
 from __future__ import annotations
 
 import heapq
+import sys
+import threading
 import warnings
 import zlib
 from collections import Counter, deque
+from concurrent.futures import ThreadPoolExecutor, wait
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field, replace
 
 import jax
@@ -48,11 +54,17 @@ from repro.configs.iemas_cluster import (DEFAULT_ROUTER, MODEL_CLASSES,
 from repro.core.adversary import AdversaryMix, AdversaryPolicy
 from repro.core.mechanism import AgentInfo, CompletionObs, IEMASRouter, Request
 from repro.core.pricing import TokenPrices
-from repro.serving.engine import AgentEngine
+from repro.serving.engine import AgentEngine, weights_devices
 from repro.serving.evaluator import SimulatedSkillEvaluator
 from repro.serving.telemetry import TelemetryTracker
 from repro.serving.workload import DialogueScript
 from repro.utils.timing import phase_scope
+
+# the interpreter's thread switch interval while several workers serve: a
+# worker whose device has finished takes its clock reading once it has the
+# interpreter back, so this bounds how late that reading can come (the
+# default, 5 ms, is a fifth of a serve)
+_SWITCH_S = 1e-4
 
 
 def agent_device(index: int, devices: list):
@@ -95,6 +107,18 @@ class RequestRecord:
     # turn's prompt (dialogue causality, Appendix C.1)
     output_tokens: np.ndarray = field(
         default_factory=lambda: np.zeros(0, np.int32))
+
+
+@dataclass(frozen=True)
+class _Draws:
+    """One dispatch's random draws, taken in decision order: whether it
+    fails, its straggle factor and its quality score, and its place among
+    same-time completions."""
+
+    failed: bool
+    straggle: float
+    quality: float
+    seq: int
 
 
 @dataclass
@@ -163,6 +187,11 @@ class SimCluster:
         self.now = 0.0
         self._completions: list = []  # heap of (time, seq, record, router_obs)
         self._seq = 0
+        # concurrent dispatch (`dispatch`): one thread at a time runs host
+        # code; the workers' pool is built on the first batch that needs it
+        self._host = threading.Lock()
+        self._pool: ThreadPoolExecutor | None = None
+        self._drawn: dict[int, _Draws] = {}   # id(decision) -> its draws
 
     def _add_runtime(self, prof: AgentProfile, fail_prob, straggle_prob,
                      cache_slots, max_new_tokens):
@@ -236,6 +265,26 @@ class SimCluster:
         return {aid: max(0, rt.info.capacity - inflight.get(aid, 0))
                 for aid, rt in self.agents.items()}
 
+    def _draw(self, decision) -> _Draws:
+        """Failure injection, straggling and the quality score of one
+        dispatch to a live agent, drawn from the cluster's generators in the
+        order serial dispatch draws them; a failure quarantines the agent at
+        once, so the batch's later requests to it fail too."""
+        rt = self.agents[decision.agent_id]
+        seq = self._seq
+        self._seq += 1
+        if rt.down_until > self.now or self.rng.random() < rt.fail_prob:
+            rt.down_until = max(rt.down_until,
+                                self.now + self.quarantine_cooldown)
+            return _Draws(True, 1.0, 0.0, seq)
+        straggle = (rt.straggle_factor
+                    if self.rng.random() < rt.straggle_prob else 1.0)
+        req = decision.request
+        quality = self.evaluator.score(
+            rt.info.scale, req.domain in rt.info.domains,
+            float(req.meta.get("difficulty", 0.5)))
+        return _Draws(False, straggle, quality, seq)
+
     def execute(self, decision, router) -> RequestRecord | None:
         """Dispatch one routed request to its agent and schedule completion."""
         req = decision.request
@@ -243,17 +292,15 @@ class SimCluster:
             return None
         rt = self.agents[decision.agent_id]
         self.telemetry.on_dispatch(rt.info.agent_id, self.now)
+        draws = self._drawn.pop(id(decision), None) or self._draw(decision)
 
-        # failure injection
-        if rt.down_until > self.now or self.rng.random() < rt.fail_prob:
-            rt.down_until = max(rt.down_until, self.now + self.quarantine_cooldown)
+        if draws.failed:
             rec = RequestRecord(req, rt.info.agent_id, self.now, 0.0, 0.0, 0.0,
                                 len(req.tokens), 0, 0, 0.0, 0.0,
                                 decision.welfare_weight, failed=True)
             obs = CompletionObs(0.0, len(req.tokens), 0, 0, 0.0, failed=True)
             heapq.heappush(self._completions,
-                           (self.now + 0.05, self._seq, rec, obs))
-            self._seq += 1
+                           (self.now + 0.05, draws.seq, rec, obs))
             return rec
 
         # DAG steps serve under their own session key with parent-session
@@ -264,14 +311,9 @@ class SimCluster:
                                  max_new_tokens=req.max_new_tokens,
                                  parents=req.meta.get("parent_sessions", ()))
         queue = self.telemetry.agent_inflight.get(rt.info.agent_id, 1) - 1
-        straggle = (rt.straggle_factor
-                    if self.rng.random() < rt.straggle_prob else 1.0)
-        latency = result.ttft * straggle + 0.001 * max(0, queue)
-        total = result.total_time * straggle + 0.001 * max(0, queue)
-
-        dom_match = req.domain in rt.info.domains
-        difficulty = float(req.meta.get("difficulty", 0.5))
-        quality = self.evaluator.score(rt.info.scale, dom_match, difficulty)
+        latency = result.ttft * draws.straggle + 0.001 * max(0, queue)
+        total = result.total_time * draws.straggle + 0.001 * max(0, queue)
+        quality = draws.quality
 
         cost = (rt.info.prices.miss * (result.n_prompt - result.n_hit)
                 + rt.info.prices.hit * result.n_hit
@@ -291,9 +333,95 @@ class SimCluster:
             obs = (pol.report(obs, quality) if pol is not None
                    else replace(obs, audit_quality=quality))
         self.telemetry.on_busy(rt.info.agent_id, total)
-        heapq.heappush(self._completions, (self.now + total, self._seq, rec, obs))
-        self._seq += 1
+        heapq.heappush(self._completions,
+                       (self.now + total, draws.seq, rec, obs))
         return rec
+
+    def dispatch(self, decisions, router) -> list:
+        """Execute one route batch's decisions; returns each one's
+        `execute` result (None for a dead dispatch target), in order.
+
+        The decisions are grouped by the devices that hold their agents'
+        weights. With one such group every request runs in the caller's
+        thread, one after another. With several, each group runs on a
+        worker of a pool with at most one worker a device, in decision
+        order, while the caller runs the requests of agents without weights
+        (analytic engines, dead targets); the random draws are taken in
+        decision order first, so the same requests fail whichever device
+        finishes first. A worker holds the host lock for the whole of each
+        `execute` and lets go only while its engine waits on the device
+        (`AgentEngine.wait`), so the cluster's state, the profiler and
+        anything wrapping `execute` or `serve` see one request at a time;
+        meanwhile the interpreter switches threads every `_SWITCH_S`.
+        Every request has been served when this returns; a worker's
+        exception is raised here.
+
+        Profiler span ``dispatch`` (counters ``chips``, the device groups,
+        and ``requests``), where there is a request to serve."""
+        if not decisions:
+            return []
+        groups: dict[frozenset, list[int]] = {}
+        for i, dec in enumerate(decisions):
+            rt = self.agents.get(dec.agent_id)
+            key = weights_devices(rt.engine) if rt is not None else frozenset()
+            groups.setdefault(key, []).append(i)
+        local = groups.pop(frozenset(), [])
+        with phase_scope(self.profiler, "dispatch") as span:
+            if len(groups) <= 1:
+                out = [self.execute(dec, router) for dec in decisions]
+            else:
+                out = self._dispatch_groups(decisions, local,
+                                            list(groups.values()), router)
+            span.set(chips=len(groups), requests=len(decisions))
+        return out
+
+    def _dispatch_groups(self, decisions, local: list, groups: list,
+                         router) -> list:
+        """`dispatch` over several device groups (its docstring)."""
+        for dec in decisions:
+            if dec.agent_id in self.agents:
+                self._drawn[id(dec)] = self._draw(dec)
+        out = [None] * len(decisions)
+        engines = {self.agents[decisions[i].agent_id].engine
+                   for idx in groups for i in idx}
+        if self._pool is None:
+            devices = set().union(*(weights_devices(rt.engine)
+                                    for rt in self.agents.values()))
+            self._pool = ThreadPoolExecutor(len(devices),
+                                            thread_name_prefix="dispatch")
+        for eng in engines:
+            eng.wait = self._device_wait
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(min(interval, _SWITCH_S))
+        try:
+            for i in local:
+                out[i] = self.execute(decisions[i], router)
+            futures = [self._pool.submit(self._serve_group, decisions, idx,
+                                         router, out) for idx in groups]
+            wait(futures)
+            for f in futures:
+                f.result()
+        finally:
+            sys.setswitchinterval(interval)
+            for eng in engines:
+                eng.wait = nullcontext
+            self._drawn.clear()
+        return out
+
+    def _serve_group(self, decisions, idx: list, router, out: list) -> None:
+        """One worker's share of a batch: its device's requests, in order."""
+        for i in idx:
+            with self._host:
+                out[i] = self.execute(decisions[i], router)
+
+    @contextmanager
+    def _device_wait(self):
+        """An engine's wait on its device: the host lock is free meanwhile."""
+        self._host.release()
+        try:
+            yield
+        finally:
+            self._host.acquire()
 
     def next_completion_time(self) -> float | None:
         """Virtual time of the earliest scheduled completion (event hook)."""
@@ -435,13 +563,16 @@ def run_workload(cluster: SimCluster, router, dialogues: list[DialogueScript],
             telem = cluster.telemetry.snapshot(cluster.now)
             decisions = router.route_batch(batch, telem,
                                            free_slots=cluster.free_slots())
+            records = iter(cluster.dispatch(
+                [dec for dec in decisions if dec.agent_id is not None],
+                router))
             unmatched = []
             for dec in decisions:
                 did = dec.request.dialogue_id
                 if dec.agent_id is None:
                     unmatched.append(did)  # retry, keeping queue priority
                     continue
-                if cluster.execute(dec, router) is None:
+                if next(records) is None:
                     # dead dispatch target (agent removed from the cluster
                     # but not the router): report it as a failure so the
                     # router quarantines it and clears its pending entry,

@@ -21,6 +21,7 @@ tokens read back once.
 from __future__ import annotations
 
 import time
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 
 import jax
@@ -107,6 +108,13 @@ def _moe_counters(work) -> dict:
     return {"moe_rows": int(work[0]), "moe_experts": int(work[1])}
 
 
+def weights_devices(engine) -> frozenset:
+    """The devices that hold ``engine``'s weights, where its programs run
+    (none for an engine without weights, such as an analytic one)."""
+    return frozenset(d for a in jax.tree.leaves(getattr(engine, "params", None))
+                     for d in a.devices())
+
+
 def _shared_fns(cfg: ModelConfig, max_len: int):
     key = (cfg, max_len)
     if key not in _SHARED:
@@ -157,6 +165,10 @@ class AgentEngine:
         # the serving stack's RoutingProfiler, attached by the cluster:
         # engine.serve / prefill|extend / decode spans (None: no-ops)
         self.profiler = None
+        # the context in which `serve` waits on the device: a cluster that
+        # serves several engines at once installs one that lets other
+        # threads run host code meanwhile
+        self.wait = nullcontext
 
     def warmup(self, prefill_buckets=(32, 64, 128, 256, 512),
                extend_buckets=(16, 32, 64)) -> None:
@@ -231,7 +243,8 @@ class AgentEngine:
         under ``dialogue_id`` regardless.
 
         The whole call is the profiler's ``engine.serve`` span (``session``,
-        the routing call's ``batch``; counters ``mode``, ``n_prompt``,
+        the routing call's ``batch``, ``chip``, the id of the device that
+        holds the weights; counters ``mode``, ``n_prompt``,
         ``n_hit``, ``n_gen``, ``evicted``); its self time is the host
         preparation around the ``engine.prefill``/``engine.extend`` and
         ``engine.decode`` (counters ``steps``, the decode steps run, and
@@ -243,8 +256,9 @@ class AgentEngine:
         prof = self.profiler
         evictions = self.evictions
         with phase_scope(prof, "engine.serve", session=dialogue_id,
-                         batch=prof.batch if prof is not None else -1
-                         ) as span:
+                         batch=prof.batch if prof is not None else -1,
+                         chip=(min(d.id for d in weights_devices(self))
+                               if prof is not None else -1)) as span:
             res, mode = self._serve(dialogue_id,
                                     np.asarray(prompt, dtype=np.int32), now,
                                     max_new_tokens or self.max_new, parents)
@@ -277,8 +291,14 @@ class AgentEngine:
                          else "engine.extend") as first:
             logits, cache, work = self._first_logits(prompt, sess, mode,
                                                      n_hit)
-            jax.block_until_ready(logits)
-            t_first = time.perf_counter()
+            with self.wait():
+                jax.block_until_ready(logits)
+                t_first = time.perf_counter()
+            # under a cluster's `wait` the host may still be serving another
+            # engine when the logits are ready; that turn is not this
+            # request's time
+            lent = (time.perf_counter() - t_first
+                    if self.wait is not nullcontext else 0.0)
             if work is not None:
                 # the program has finished: this reads its ready counters
                 # (a span takes its counters only while it is open)
@@ -288,12 +308,14 @@ class AgentEngine:
         # counters) read back once (the output buffer's size is bucketed so
         # few sizes compile)
         with phase_scope(prof, "engine.decode") as decode:
-            out, cache = jax.block_until_ready(self._generate_j(
-                self.params, cache, logits, np.int32(max_new),
-                size=_bucket(max_new, lo=8)))
-            out, work = jax.device_get((out, cache.get(MOE_STATS)))
-            gen = out[:max_new]
-            t_end = time.perf_counter()
+            out, cache = self._generate_j(self.params, cache, logits,
+                                          np.int32(max_new),
+                                          size=_bucket(max_new, lo=8))
+            with self.wait():
+                out, cache = jax.block_until_ready((out, cache))
+                out, work = jax.device_get((out, cache.get(MOE_STATS)))
+                gen = out[:max_new]
+                t_end = time.perf_counter()
             decode.set(steps=max_new, syncs=1)
             if work is not None:
                 decode.set(**_moe_counters(work))
@@ -305,7 +327,7 @@ class AgentEngine:
         self._evict_lru(now)
 
         ttft = (t_first - t0) / self.speed
-        total = (t_end - t0) / self.speed
+        total = (t_end - t0 - lent) / self.speed
         return ServeResult(gen, ttft, total, n_prompt, min(n_hit, n_prompt),
                            len(gen)), mode
 

@@ -105,9 +105,10 @@ class RoutingProfiler:
     invocation, inside which the IEMAS router nests ``phase1_predict``,
     ``price_book``, ``fused_route`` (itself ``fused.assemble`` ->
     ``fused.device`` -> ``fused.settle``), ``phase2_solve[<backend>]`` and
-    ``phase2_spill``; ``phase4_feedback`` wraps completion feedback and
-    ``engine.serve`` (``engine.prefill``/``engine.extend`` ->
-    ``engine.decode``) each engine call.  Each phase adds its wall time
+    ``phase2_spill``; ``phase4_feedback`` wraps completion feedback,
+    ``dispatch`` the serving of one route batch, and ``engine.serve``
+    (``engine.prefill``/``engine.extend`` -> ``engine.decode``) each engine
+    call, on the thread that serves it.  Each phase adds its wall time
     and one call to ``phases``/``calls``.  While a JAX profiler trace is
     being collected it also opens a ``jax.profiler.TraceAnnotation``
     named ``iemas.<name>`` carrying ``stats`` (identifiers and sizes known
@@ -731,6 +732,8 @@ class ShardEventLoop:
                          n=len(batch),
                          m=sum(aid not in quarantined for aid in free)):
             decisions = router.route_batch(batch, telem, free_slots=free)
+        records = iter(cluster.dispatch(
+            [dec for dec in decisions if dec.agent_id is not None], router))
         unmatched = []
         for dec in decisions:
             did = dec.request.dialogue_id
@@ -738,7 +741,7 @@ class ShardEventLoop:
             if dec.agent_id is None:
                 unmatched.append((did, step))
                 continue
-            if cluster.execute(dec, router) is None:
+            if next(records) is None:
                 # dead dispatch target: fault-path feedback (quarantine +
                 # pending cleanup) so the router stops matching it — same
                 # handling as run_workload (parity contract)

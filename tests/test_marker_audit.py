@@ -20,6 +20,7 @@ TIER1_MODULES = {
     "test_churn_storm",
     "test_column_market",
     "test_dag_workload",
+    "test_dispatch",
     "test_docs",
     "test_engine_decode",
     "test_exploration",

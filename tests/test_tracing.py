@@ -87,7 +87,7 @@ def test_span_names_and_stats(traced):
     assert {"iemas.route_batch", "iemas.price_book", "iemas.fused_route",
             "iemas.fused.assemble", "iemas.fused.device",
             "iemas.fused.settle", "iemas.phase2_spill",
-            "iemas.phase4_feedback", "iemas.engine.serve",
+            "iemas.phase4_feedback", "iemas.dispatch", "iemas.engine.serve",
             "iemas.engine.prefill", "iemas.engine.extend",
             "iemas.engine.decode"} <= names
     want = {"iemas.route_batch": {"batch", "n", "m"},
@@ -95,8 +95,9 @@ def test_span_names_and_stats(traced):
                                    "retraces"},
             "iemas.phase4_feedback": {"req", "n_prompt", "n_hit",
                                       "promised"},
-            "iemas.engine.serve": {"session", "batch", "mode", "n_prompt",
-                                   "n_hit", "n_gen", "evicted"},
+            "iemas.dispatch": {"chips", "requests"},
+            "iemas.engine.serve": {"session", "batch", "chip", "mode",
+                                   "n_prompt", "n_hit", "n_gen", "evicted"},
             "iemas.engine.decode": {"steps", "syncs"}}
     for name, keys in want.items():
         for s in _named(spans, name):
@@ -175,6 +176,11 @@ def test_counters_equal_ground_truth(traced):
     assert sum(s["steps"] for s in decode) == sum(r.n_gen for r in records)
     # the decode loop runs on the device: one read-back per request
     assert sum(s["syncs"] for s in decode) == len(decode) == len(records)
+    # each route batch is one dispatch, here to the one device's group
+    dispatch = [s[3] for s in _named(spans, "iemas.dispatch")]
+    assert sum(s["requests"] for s in dispatch) == len(records)
+    assert {s["chips"] for s in dispatch} == {1}
+    assert {s["chip"] for s in serves} == {jax.devices()[0].id}
 
 
 def _analytic_run(profiler):
